@@ -48,6 +48,7 @@ def main():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for r_shards in (2, 4, 8):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"    # CPU rehearsal: never the chip
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={r_shards}"
         env["PYTHONPATH"] = os.path.join(repo, "src")
         out = subprocess.run(

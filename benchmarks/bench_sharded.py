@@ -1,10 +1,11 @@
 """DESIGN.md §11: weak scaling of sharded serving.
 
-Fixed N PER DEVICE, mesh sizes 1/2/4/8 — each size runs in a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (conftest
-keeps the parent single-device) and serves a fixed request mix through a
-``ShardedIndexStore`` + ``QueryServer``. Inside each subprocess telemetry
-is enabled, so every mesh size reports device-fenced per-phase span
+Fixed N PER DEVICE, mesh sizes 1/2/4/8 — each size runs in a CPU
+subprocess (``JAX_PLATFORMS=cpu``,
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``) and serves a
+fixed request mix through a ``ShardedIndexStore`` + ``QueryServer``.
+Inside each subprocess telemetry is enabled, so every mesh size reports
+device-fenced per-phase span
 summaries (gather / local_traverse / exchange / merge) alongside serve
 latency, distributed-refit latency, and build time.
 
@@ -94,11 +95,20 @@ print("RESULT " + json.dumps({
 """
 
 
-def _run_mesh(r_shards: int, params: dict) -> dict:
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _cpu_child_env(repo: str) -> dict:
+    """Environment of a CPU rehearsal child: eight forced host devices,
+    and JAX_PLATFORMS=cpu so the child never reaches for a chip (a chip
+    belongs to one process; the parent or the caller may hold it)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(repo, "src")
+    return env
+
+
+def _run_mesh(r_shards: int, params: dict) -> dict:
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = _cpu_child_env(repo)
     code = (_CODE.replace("__R__", str(r_shards))
             .replace("__N_PER__", str(params["n_per_shard"]))
             .replace("__Q__", str(params["n_queries"]))
@@ -119,9 +129,7 @@ def _smoke_conformance():
     """Hard oracle check, single subprocess: sharded serving on 2 shards
     answers a knn mix identically to the brute-force distance matrix."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(repo, "src")
+    env = _cpu_child_env(repo)
     code = """
 import numpy as np, jax.numpy as jnp
 from repro.compat import make_mesh

@@ -14,6 +14,7 @@ import sys
 import traceback
 
 from repro import telemetry
+from repro.compile_cache import configure_compile_cache
 from repro.core.route_table import hardware_fingerprint
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -41,6 +42,7 @@ PRESERVE = {"service": ("pipeline",), "distributed": ("weak_scaling",)}
 
 def main():
     only = sys.argv[1:] or None
+    print(f"# compile cache: {configure_compile_cache()}", file=sys.stderr)
     print("name,us_per_call,derived")
     failed = []
     # telemetry on for the whole sweep; drained per module so each

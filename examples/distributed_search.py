@@ -5,13 +5,14 @@ and the distributed refit republishes without interrupting serving.
 
     PYTHONPATH=src python examples/distributed_search.py
 
-(Re-execs itself with XLA_FLAGS to get 8 host devices.)
+(Re-execs itself on the CPU backend with XLA_FLAGS to get 8 host devices.)
 """
 import os
 import sys
 
 if "XLA_FLAGS" not in os.environ:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import jax

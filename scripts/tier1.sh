@@ -14,6 +14,9 @@ else
 fi
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# tier-1 is the CPU lane: kernels in interpret mode (the sanitizer lane
+# below needs it, and a TPU backend refuses it); the chip has chip_smoke.py
+export JAX_PLATFORMS=cpu
 
 # reprolint (DESIGN.md §9): lock discipline + tracer hygiene + span
 # hygiene (TEL001) + the launch-capture kernel sanitizer. A hard gate —

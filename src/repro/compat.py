@@ -1,61 +1,13 @@
-"""JAX API compatibility layer (sharding / shard_map drift).
+"""The sharding names the rest of the repo (and the test subprocesses)
+import from one place, spelled as jax 0.9 spells them.
 
-Resolves the names that moved between jax 0.4.x and newer releases so the
-rest of the repo (and the test subprocesses) import from one place:
-
-  * ``AxisType``  — ``jax.sharding.AxisType`` where available, otherwise a
-    small stand-in enum (0.4.x meshes have no axis types; ``Auto`` is the
-    behavior every mesh gets there anyway).
-  * ``make_mesh`` — forwards ``axis_types=`` only when the installed
-    ``jax.make_mesh`` accepts it.
-  * ``shard_map`` — ``jax.shard_map`` on new jax, else
-    ``jax.experimental.shard_map.shard_map``; the ``check_vma=`` keyword is
-    translated to the old ``check_rep=`` spelling when needed.
+``make_mesh`` keeps jax's default axis types (``Explicit`` in jax 0.9);
+callers that want sharding left out of array types pass
+``axis_types=(AxisType.Auto, ...)``.
 """
 from __future__ import annotations
 
-import enum
-import inspect
-
-import jax
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 
 __all__ = ["AxisType", "make_mesh", "shard_map"]
-
-try:
-    from jax.sharding import AxisType  # jax >= 0.5-ish
-except ImportError:
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` on older jax: meshes are
-        implicitly fully-automatic there, so ``Auto`` is a no-op marker."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-_MAKE_MESH_HAS_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-    """``jax.make_mesh`` that tolerates the ``axis_types=`` kwarg everywhere."""
-    if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        kw["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """Version-stable ``shard_map``: new-style ``check_vma=`` is translated
-    to old-style ``check_rep=`` when the installed jax predates the rename."""
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

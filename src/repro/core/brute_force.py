@@ -33,10 +33,13 @@ __all__ = ["BruteForce", "pairwise_sq_distances"]
 
 
 def pairwise_sq_distances(x: jax.Array, y: jax.Array) -> jax.Array:
-    """(Q, N) squared euclidean distances via the MXU-friendly expansion."""
+    """(Q, N) squared euclidean distances via the MXU-friendly expansion.
+    The product runs at HIGHEST precision: a TPU's default float32
+    matmul is one bfloat16 pass, whose ~1e-2 error would pick the wrong
+    neighbours."""
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (Q, 1)
     y2 = jnp.sum(y * y, axis=-1, keepdims=True).T        # (1, N)
-    xy = x @ y.T                                         # (Q, N) — MXU
+    xy = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)  # MXU
     return jnp.maximum(x2 - 2.0 * xy + y2, 0.0)
 
 
@@ -146,6 +149,16 @@ class BruteForce(Index):
         neg_top, idx = jax.lax.top_k(-d, k_eff)
         dists = -neg_top
         idx = idx.astype(jnp.int32)
+        if self._expanded(predicates):
+            # the expansion selects; the k winners get the exact fine
+            # distance (the loop path's formula), re-sorted by it
+            g = predicates.geom.coords
+            exact = G.distance_point_point(g[:, None, :],
+                                           self.values.coords[idx])
+            exact = jnp.where(jnp.isinf(dists), jnp.inf, exact)  # excluded
+            order = jnp.argsort(exact, axis=1, stable=True)
+            dists = jnp.take_along_axis(exact, order, 1)
+            idx = jnp.take_along_axis(idx, order, 1)
         if k_eff < k:
             pad_d = jnp.full((d.shape[0], k - k_eff), jnp.inf, d.dtype)
             pad_i = jnp.full((d.shape[0], k - k_eff), -1, jnp.int32)
@@ -174,12 +187,17 @@ class BruteForce(Index):
 
         return _map_query_blocks(block, predicates, self._block_q)
 
+    def _expanded(self, predicates) -> bool:
+        """Point queries against Points values: the MXU expansion path."""
+        return (isinstance(getattr(predicates, "geom", None), G.Points)
+                and isinstance(self.values, G.Points))
+
     def _distance_matrix(self, predicates):
         values = self.values
-        g = getattr(predicates, "geom", None)
-        if isinstance(g, G.Points) and isinstance(values, G.Points):
+        if self._expanded(predicates):
             # fast path: MXU expansion
-            return jnp.sqrt(pairwise_sq_distances(g.coords, values.coords))
+            return jnp.sqrt(pairwise_sq_distances(predicates.geom.coords,
+                                                  values.coords))
 
         def block(pred_blk):
             return jax.vmap(lambda p: P.leaf_distance(p, values))(pred_blk)

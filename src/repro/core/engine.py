@@ -58,8 +58,6 @@ _LEGACY_CROSSOVERS = {
     "pallas_max_capacity": "pallas_max_capacity",
 }
 
-_FALLBACK_TABLE = RT.RouteTable.default()
-
 
 @dataclasses.dataclass
 class EngineConfig:
@@ -71,7 +69,8 @@ class EngineConfig:
     route_table: a RouteTable (or a path to a persisted one) used for
         every routing decision through this engine; None defers to the
         ambient persisted table (``ROUTE_TABLE.json`` /
-        ``$REPRO_ROUTE_TABLE``) and finally to built-in defaults. An
+        ``$REPRO_ROUTE_TABLE``) and finally to the backend's built-in
+        table (TPU keeps every op off the traversal kernels). An
         :class:`~repro.core.index.ExecutionPolicy` table overrides this
         per call/index.
     max_executables: LRU bound on the exec_* executable cache — a long-
@@ -288,14 +287,16 @@ class QueryEngine:
     # -- route-table resolution (DESIGN.md §8 lookup order) ---------------
     def table(self, policy=None) -> RT.RouteTable:
         """Resolve the effective RouteTable: explicit policy table >
-        engine-config table > ambient persisted table > built-in defaults.
-        (``force`` is orthogonal — checked inside ``_pick``.)"""
+        engine-config table > ambient persisted table > the built-in
+        table for the backend (:func:`~repro.core.route_table.
+        builtin_route_table`). (``force`` is orthogonal — checked inside
+        ``_pick``.)"""
         t = getattr(policy, "route_table", None)
         if t is None:
             t = self.config.route_table
         if t is None:
             t = RT.default_route_table()
-        return t if t is not None else _FALLBACK_TABLE
+        return t if t is not None else RT.builtin_route_table()
 
     def _rule(self, op: str, bvh, policy) -> RT.RouteRule:
         if policy is None:

@@ -189,8 +189,22 @@ def _bcast_state(state, nq):
         state)
 
 
+def _replicated(a):
+    """`a` with its rows no longer split over an explicit mesh axis.
+
+    A ``shard_map`` over an ``Explicit`` mesh (jax 0.9's ``make_mesh``
+    default) returns arrays whose type names the axis; jax refuses to
+    scatter such rows into an unsharded buffer, so they are replicated
+    over the same mesh first. Other arrays pass through."""
+    s = a.sharding
+    if isinstance(s, jax.sharding.NamedSharding) and any(s.spec):
+        return jax.device_put(a, s.update(spec=jax.sharding.PartitionSpec()))
+    return a
+
+
 def _csr_pack(buf, counts, offsets, total):
     """(Q, cap) buffer + per-query counts -> flat (total,) CSR array."""
+    buf, counts, offsets = map(_replicated, (buf, counts, offsets))
     q, cap = buf.shape
     ar = jnp.arange(cap)[None, :]
     valid = ar < counts[:, None]

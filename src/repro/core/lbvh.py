@@ -188,8 +188,9 @@ def _refit_iterative(leaf_lo, leaf_hi, left_child, right_child):
 
 
 def _resolve_build_engine(engine: str) -> str:
-    """Resolve the build-engine selector to "pallas" (fused kernels, the
-    ISSUE 7 fast path) or "ref" (the original unfused searches).
+    """Resolve the build-engine selector to "pallas" (the fused delta-RMQ
+    build of ``kernels.lbvh_build``, a jit program on every backend) or
+    "ref" (the original unfused searches).
 
     Order (DESIGN.md §8): REPRO_ENGINE_FORCE > explicit engine arg >
     persisted RouteTable ``build_engine`` > default ("pallas" — the fused

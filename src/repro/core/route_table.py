@@ -22,9 +22,12 @@ Lookup order (most to least specific, DESIGN.md §8):
   1. explicit per-call/per-index policy  (``ExecutionPolicy.route_table``)
   2. engine-level table                  (``EngineConfig.route_table``)
   3. ``REPRO_ENGINE_FORCE``              (pins a route outright, debugging)
-  4. persisted autotuned table           (``ROUTE_TABLE.json`` /
-                                          ``$REPRO_ROUTE_TABLE``)
-  5. built-in defaults                   (:meth:`RouteTable.default`)
+  4. persisted autotuned table           (``ROUTE_TABLE.json`` at the
+                                          repo root / ``$REPRO_ROUTE_TABLE``)
+  5. built-in table for the backend      (:func:`builtin_route_table`:
+                                          :meth:`RouteTable.tpu_default` on
+                                          TPU, :meth:`RouteTable.default`
+                                          elsewhere)
 
 (3) is checked inside the engine's ``_pick`` — a force always wins over
 any table, including an explicit one; it exists for A/B debugging only.
@@ -36,13 +39,15 @@ result: all three paths are exact (§3 invariant, pinned by
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import warnings
 
 __all__ = ["RouteRule", "RouteTable", "SCHEMA_VERSION",
            "hardware_fingerprint", "default_route_table",
-           "validate_route_table"]
+           "builtin_route_table", "validate_route_table",
+           "TPU_TRAVERSAL_REASON"]
 
 SCHEMA_VERSION = 1
 
@@ -52,6 +57,16 @@ OPS = ("spatial", "knn", "callback")
 
 _ENV_TABLE = "REPRO_ROUTE_TABLE"        # path override, or "off" to disable
 _DEFAULT_BASENAME = "ROUTE_TABLE.json"
+
+#: why the built-in TPU table keeps every op off the fused traversal
+#: kernels: the TPU compiler (Mosaic) rejects their per-lane row gathers
+#: from the VMEM-staged tree (``jnp.take`` in ``kernels/bvh_traverse.py``
+#: and ``kernels/bvh_callback.py``). ``tests/test_tpu_compile.py`` keeps
+#: a strict xfail per kernel, so the PR that makes them compile sees it.
+TPU_TRAVERSAL_REASON = (
+    "the fused traversal kernels (spatial, knn, callback) do not compile "
+    "for TPU: Mosaic rejects their in-kernel row gathers with 'ValueError: "
+    "Shape mismatch in input, indices and output'")
 
 
 def hardware_fingerprint() -> dict:
@@ -115,8 +130,11 @@ class RouteTable:
     fingerprint: dict = dataclasses.field(default_factory=dict)
     build_engine: str = "auto"      # "pallas" | "ref" | "auto" (lbvh.build)
     schema_version: int = SCHEMA_VERSION
-    source: str = "defaults"        # "defaults"|"synthesized"|"autotuned"|path
+    source: str = "defaults"        # "defaults"|"tpu-rule"|"synthesized"|
+                                    # "autotuned"|path
     measurements: dict = dataclasses.field(default_factory=dict)
+    reason: str = ""                # why a built-in rule departs from the
+                                    # defaults (printed by chip_smoke.py)
 
     # -- lookup ------------------------------------------------------------
     def rule(self, op: str) -> RouteRule:
@@ -137,6 +155,18 @@ class RouteTable:
             "knn": RouteRule(pallas_max_capacity=256),
             "callback": RouteRule(pallas_max_capacity=1024),
         })
+
+    @classmethod
+    def tpu_default(cls) -> "RouteTable":
+        """Built-in rules on a TPU backend: the defaults with
+        ``pallas_max_nodes=0`` for every op, so no query routes to a
+        traversal kernel the TPU compiler refuses
+        (:data:`TPU_TRAVERSAL_REASON`). Brute force and the while loop
+        keep their default crossovers."""
+        base = cls.default()
+        return cls(rules={op: r.replace(pallas_max_nodes=0)
+                          for op, r in base.rules.items()},
+                   source="tpu-rule", reason=TPU_TRAVERSAL_REASON)
 
     @classmethod
     def single(cls, *, build_engine: str = "auto", source: str = "synthesized",
@@ -235,14 +265,13 @@ def _default_path() -> str | None:
     env = os.environ.get(_ENV_TABLE)
     if env:
         return None if env.lower() in ("off", "none", "0") else env
-    # repo checkout layout: src/repro/core/route_table.py -> repo root
+    # repo checkout layout: src/repro/core/route_table.py -> repo root.
+    # Only the repo's own table counts: a stray ROUTE_TABLE.json in the
+    # working directory must not change routing.
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))))
-    for cand in (os.path.join(root, _DEFAULT_BASENAME),
-                 os.path.join(os.getcwd(), _DEFAULT_BASENAME)):
-        if os.path.exists(cand):
-            return cand
-    return None
+    cand = os.path.join(root, _DEFAULT_BASENAME)
+    return cand if os.path.exists(cand) else None
 
 
 def default_route_table() -> RouteTable | None:
@@ -269,6 +298,17 @@ def default_route_table() -> RouteTable | None:
     _CACHE.clear()
     _CACHE[key] = table
     return table
+
+
+@functools.lru_cache(maxsize=None)
+def builtin_route_table() -> RouteTable:
+    """Lookup step 5: the built-in table for JAX's default backend.
+    Resolved when first asked, never at import, so importing the library
+    does not initialize a backend."""
+    import jax
+    if jax.default_backend() == "tpu":
+        return RouteTable.tpu_default()
+    return RouteTable.default()
 
 
 def _reset_cache() -> None:

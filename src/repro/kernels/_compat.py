@@ -1,29 +1,11 @@
-"""Pallas TPU API compatibility across JAX versions.
-
-The TPU compiler-params dataclass was renamed between JAX releases:
-``pltpu.TPUCompilerParams`` (jax <= 0.4.x / early 0.5.x) became
-``pltpu.CompilerParams`` (newer releases). Every kernel in this package
-resolves the name through here so the same source runs on both.
-"""
+"""The one spelling of Pallas TPU compiler params every kernel uses."""
 from __future__ import annotations
 
 from jax.experimental.pallas import tpu as pltpu
 
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-elif hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:  # pragma: no cover - ancient jax: run kernels without params
-    CompilerParams = None
-
 
 def compiler_params(*, dimension_semantics=None, **kw):
-    """Build compiler params for ``pl.pallas_call`` on any JAX version.
-
-    Returns None when no params class exists (pallas_call accepts that).
-    """
-    if CompilerParams is None:  # pragma: no cover
-        return None
+    """``pltpu.CompilerParams`` with ``dimension_semantics`` as a tuple."""
     if dimension_semantics is not None:
         kw["dimension_semantics"] = tuple(dimension_semantics)
-    return CompilerParams(**kw)
+    return pltpu.CompilerParams(**kw)

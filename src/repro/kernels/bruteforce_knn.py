@@ -64,7 +64,9 @@ def _knn_kernel(q_ref, p_ref, dout_ref, iout_ref, run_d, run_i,
     p = p_ref[...].astype(jnp.float32)                        # (bn, D)
     q2 = jnp.sum(q * q, axis=1, keepdims=True)                # (bq, 1)
     p2 = jnp.sum(p * p, axis=1)[None, :]                      # (1, bn)
+    # HIGHEST: a TPU's default float32 matmul is one bfloat16 pass
     qp = jax.lax.dot_general(q, p, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # MXU
     d2 = jnp.maximum(q2 - 2.0 * qp + p2, 0.0)                 # (bq, bn)
 

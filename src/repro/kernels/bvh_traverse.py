@@ -28,11 +28,12 @@ Two kernels:
     traversal, in rope order with tau-tightening.
 
 On CPU backends the kernels run in interpret mode (identical semantics,
-what the oracle tests assert against). On real TPU the tree tables must
-fit VMEM (~16 MB): ~2¹⁷ nodes (~6·10⁴ leaves) at dim ≤ 8 keeps the staged
-boxes + int tables + output blocks inside budget; larger trees stay on
-the vmapped while-loop path (``EngineConfig.pallas_max_nodes`` enforces
-this).
+what the oracle tests assert against). They do not compile for TPU yet:
+Mosaic rejects the per-lane row gathers (``_take``) with "Shape mismatch
+in input, indices and output", so the built-in TPU route table
+(``RouteTable.tpu_default``) keeps every op off them. Were they to
+compile, the tree tables would still have to fit VMEM (~16 MB): ~2¹⁷
+nodes (~6·10⁴ leaves) at dim ≤ 8, the default ``pallas_max_nodes``.
 """
 from __future__ import annotations
 

@@ -34,12 +34,14 @@ the (L, N, 2*dim) table to rows gathered at ``k*N + first`` — one flat
 index vector instead of a two-level fancy gather (the other profiled
 hotspot). Same float min ops in the same order: identical bounds.
 
-A Pallas TPU kernel (`karras_ranges_pallas`) runs the same two searches
+A Pallas kernel (`karras_ranges_pallas`) spells the same two searches
 with direct xor/clz delta evaluation against the key arrays staged whole
-in VMEM (3 int32 tables — 12 B/leaf — far under the ~16 MB budget at the
-engine's ``pallas_max_nodes``), a block of internal nodes per grid cell.
-On non-TPU backends the jit twin is the fast path (interpret mode would
-simulate the kernel op-by-op); `karras_ranges` picks statically.
+in VMEM (3 int32 tables, 12 B/leaf, declared up to
+:data:`PALLAS_MAX_LEAVES` leaves), a block of internal nodes per grid
+cell. It is OFF the TPU route: Mosaic cannot lower its per-lane 1-D key
+gathers (:data:`PALLAS_TPU_REASON`). It runs in interpret mode only, as
+the REPRO_SANITIZE twin check; `karras_ranges` serves every build, on
+every backend, with the jit twin.
 """
 from __future__ import annotations
 
@@ -55,9 +57,22 @@ from ._compat import compiler_params
 from .ops import _round_up
 
 __all__ = ["karras_ranges", "karras_ranges_fused", "karras_ranges_pallas",
-           "aabb_rmq"]
+           "aabb_rmq", "PALLAS_MAX_LEAVES", "PALLAS_TPU_REASON"]
 
 _BIG = jnp.iinfo(jnp.int32).max      # plain int: safe to bake in under jit
+
+#: largest build the Pallas ranges kernel is declared for: its three
+#: whole-array key tables (12 B/leaf) must fit VMEM next to the output
+#: blocks, so 2^20 leaves (12 MB) is the ceiling — a 2^22-leaf build
+#: (48 MB of keys) cannot be staged at all
+PALLAS_MAX_LEAVES = 1 << 20
+
+#: why `karras_ranges` never picks the kernel on TPU (the TPU compiler's
+#: own message; ``tests/test_tpu_compile.py`` holds it as a strict xfail)
+PALLAS_TPU_REASON = (
+    "karras_ranges_pallas does not compile for TPU: Mosaic rejects its "
+    "1-D key gathers (jnp.take) with 'NotImplementedError: Only 2D gather "
+    "is supported'")
 
 
 # ---------------------------------------------------------------------------
@@ -184,7 +199,12 @@ def _karras_kernel(hi_ref, lo_ref, idx_ref, first_ref, last_ref, gamma_ref,
 
 def karras_ranges_pallas(hi, lo, idx, n: int, max_log2: int, *,
                          bn: int = 512, interpret: bool | None = None):
-    """Pallas spelling of :func:`karras_ranges_fused` (bit-identical ints)."""
+    """Pallas spelling of :func:`karras_ranges_fused` (bit-identical ints),
+    declared for at most :data:`PALLAS_MAX_LEAVES` leaves."""
+    if n > PALLAS_MAX_LEAVES:
+        raise ValueError(f"karras_ranges_pallas is declared for at most "
+                         f"{PALLAS_MAX_LEAVES} leaves (got {n}): the key "
+                         "tables would not fit VMEM")
     if interpret is None:
         interpret = sanitize.interpret_default()
     n1 = n - 1
@@ -206,11 +226,11 @@ def karras_ranges_pallas(hi, lo, idx, n: int, max_log2: int, *,
 
 
 def karras_ranges(hi, lo, idx, n: int, max_log2: int):
-    """Backend-static dispatch: the Pallas kernel on TPU, the delta-RMQ jit
-    twin elsewhere (interpret mode would simulate the kernel op-by-op and
-    lose to the twin; both produce identical integers). Under
-    REPRO_SANITIZE both twins run on concrete inputs and must agree
-    bit-for-bit — the build-conformance invariant, checked live."""
+    """The build's range search: the delta-RMQ jit twin on every backend
+    (the kernel is off the TPU route, :data:`PALLAS_TPU_REASON`, and
+    interpret mode would simulate it op-by-op elsewhere). Under
+    REPRO_SANITIZE on concrete inputs the kernel runs too and both must
+    agree bit-for-bit — the build-conformance invariant, checked live."""
     if sanitize.enabled() and sanitize.is_concrete(hi, lo, idx):
         pk = karras_ranges_pallas(hi, lo, idx, n, max_log2)
         fk = karras_ranges_fused(hi, lo, idx, n, max_log2)
@@ -223,8 +243,6 @@ def karras_ranges(hi, lo, idx, n: int, max_log2: int):
                         (fk, "karras_ranges_fused")):
             sanitize.check_karras(*r, n=n, kernel=kern)
         return fk
-    if jax.default_backend() == "tpu":
-        return karras_ranges_pallas(hi, lo, idx, n, max_log2)
     return karras_ranges_fused(hi, lo, idx, n, max_log2)
 
 
@@ -260,14 +278,9 @@ def aabb_rmq(leaf_lo, leaf_hi, first, last, max_log2: int):
 # reprolint sanitizer spec (analysis/pallas_trace.py)
 # ---------------------------------------------------------------------------
 
-#: largest build the pallas ranges kernel is declared for: 2^20 leaves at
-#: 12 B/leaf of key tables stays well inside the 16 MB VMEM budget
-REPROLINT_MAX_LEAVES = 1 << 20
-
-
 def REPROLINT_SPECS():
     def ranges_launch():
-        n = REPROLINT_MAX_LEAVES
+        n = PALLAS_MAX_LEAVES
         z = jnp.zeros((n,), jnp.uint32)
         karras_ranges_pallas(z, z, z, n, max_log2=20, interpret=True)
 

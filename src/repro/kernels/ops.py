@@ -23,8 +23,8 @@ __all__ = ["morton64", "bruteforce_knn", "ray_box_nearest", "flash_attention"]
 
 
 def _interpret() -> bool:
-    # REPRO_SANITIZE forces interpret mode even on TPU (read at trace
-    # time — process-stable; see kernels/sanitize.py)
+    # interpret off TPU, compiled on TPU (read at trace time — process-
+    # stable; see kernels/sanitize.py)
     return sanitize.interpret_default()
 
 

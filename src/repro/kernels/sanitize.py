@@ -3,7 +3,10 @@
 ``REPRO_SANITIZE=1`` flips every Pallas kernel in this package into
 interpret mode (the kernel body runs as pure JAX op-by-op — OOB block
 reads fault instead of wrapping) and arms the output assertions below on
-every EAGER kernel call. The checks are the kernels' public contracts:
+every EAGER kernel call. It is a CPU lane: interpret mode never runs on
+a chip, so a kernel asked for its interpret default on a TPU backend
+with REPRO_SANITIZE set raises. The checks are the kernels' public
+contracts:
 
   * spatial fill — counts in [0, n], collected indices in [-1, n);
   * kNN — distances non-NaN and ascending per row, indices in [-1, n);
@@ -34,10 +37,16 @@ def enabled() -> bool:
 
 def interpret_default() -> bool:
     """Interpret-mode default for kernels whose caller passed None:
-    non-TPU backends always interpret; REPRO_SANITIZE forces it even on
-    TPU so the sanitizer sees pure-JAX kernel semantics."""
+    non-TPU backends always interpret, TPU never does (REPRO_SANITIZE,
+    which needs interpret mode, is refused there)."""
     import jax
-    return enabled() or jax.default_backend() != "tpu"
+    if jax.default_backend() != "tpu":
+        return True
+    if enabled():
+        raise RuntimeError("REPRO_SANITIZE runs kernels in interpret mode, "
+                           "which is not allowed on a TPU backend; unset it "
+                           "or run with JAX_PLATFORMS=cpu")
+    return False
 
 
 def is_concrete(*arrays) -> bool:

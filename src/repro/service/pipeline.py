@@ -101,6 +101,9 @@ class PipelineStatsSnapshot:
     maintenance_pending: int = 0   # gauge: queued + in-flight updates
     maintenance_errors: int = 0
     stalled_behind_maintenance: int = 0
+    #: the exception of the newest failed update (None when none failed)
+    #: — kept so a caller can re-raise what ``maintenance_errors`` counts
+    last_maintenance_error: BaseException | None = None
 
     @property
     def occupancy(self) -> float:
@@ -186,6 +189,7 @@ class PipelineStats:
                           for f in self._COUNTER_FIELDS}
         self._gauges = {f: self.registry.gauge(f"pipeline.{f}")
                         for f in self._GAUGE_FIELDS}
+        self.last_maintenance_error: BaseException | None = None
         if legacy:
             unknown = sorted(set(legacy) - set(self._FIELDS))
             if unknown:
@@ -365,7 +369,8 @@ class ServingPipeline:
 
     def wait_maintenance_idle(self, timeout: float = 30.0) -> bool:
         """Block until no update is queued or in flight (for tests/benches
-        that need a published version before asserting)."""
+        that need a published version before asserting). A failed update
+        does not raise here: ``stats().last_maintenance_error`` holds it."""
         deadline = time.perf_counter() + timeout
         with self._maint_cv:
             while self._maint or self._maint_inflight:
@@ -621,27 +626,31 @@ class ServingPipeline:
                     self._maint_cv.wait()
                 name, values = self._maint.popitem(last=False)
                 self._maint_inflight += 1
-            action, failed = None, False
+            action, error = None, None
             try:
                 # the slow part: shadow build/refit outside every lock the
                 # serving path touches; publication inside is one dict swap
                 with TEL.span("pipeline.maintenance", index=name):
                     action = self.store.update(name, values).action
-            except Exception:
-                failed = True
+            except Exception as err:
+                error = err
             finally:
+                # account BEFORE signalling idle: a caller returning from
+                # wait_maintenance_idle must see this update in stats()
+                with self._cv:
+                    s = self._stats
+                    if error is not None:
+                        s.maintenance_errors += 1
+                        s.last_maintenance_error = error
+                    elif action is not None:
+                        s.swap_count += 1
+                        if action == "refit":
+                            s.refits += 1
+                        else:
+                            s.rebuilds += 1
                 with self._maint_cv:
                     self._maint_inflight -= 1
                     pending = len(self._maint) + self._maint_inflight
                     self._maint_cv.notify_all()
             with self._cv:
-                s = self._stats
-                s.maintenance_pending = pending
-                if failed:
-                    s.maintenance_errors += 1
-                else:
-                    s.swap_count += 1
-                    if action == "refit":
-                        s.refits += 1
-                    else:
-                        s.rebuilds += 1
+                self._stats.maintenance_pending = pending

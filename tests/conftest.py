@@ -23,8 +23,10 @@ except ImportError:
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 600):
-    """Run python code in a subprocess with N fake host devices."""
+    """Run python code in a CPU subprocess with N fake host devices
+    (JAX_PLATFORMS=cpu: a child must never reach for a chip)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     r = subprocess.run([sys.executable, "-c", code], env=env,

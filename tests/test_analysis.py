@@ -118,7 +118,8 @@ def test_collect_files_skips_fixtures_and_pycache():
 
 
 def _cli(*argv):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "repro.analysis", *argv],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=300)

@@ -89,6 +89,29 @@ def test_bvh_build_engine_kwarg():
     _assert_trees_identical(a.tree, b.tree)
 
 
+def test_karras_kernel_refuses_more_leaves_than_declared():
+    """The kernel stages its key tables whole in VMEM: past its declared
+    leaf limit it raises instead of building a program that cannot fit."""
+    from repro.kernels import lbvh_build as KB
+    n = KB.PALLAS_MAX_LEAVES + 1
+    z = jnp.zeros((n,), jnp.uint32)
+    with pytest.raises(ValueError, match="at most"):
+        KB.karras_ranges_pallas(z, z, z, n, 21)
+
+
+def test_builtin_route_table_keeps_tpu_off_the_traversal_kernels():
+    """Lookup step 5: the defaults off a TPU; on one, every op's
+    ``pallas_max_nodes`` is 0 and the table says why."""
+    from repro.core.route_table import builtin_route_table
+    assert builtin_route_table().source == "defaults"     # CPU here
+    tpu = RouteTable.tpu_default()
+    assert tpu.source == "tpu-rule" and "do not compile" in tpu.reason
+    for op in ("spatial", "knn", "callback"):
+        assert tpu.rule(op).pallas_max_nodes == 0
+        assert tpu.rule(op).bf_max_work == \
+            RouteTable.default().rule(op).bf_max_work
+
+
 # ---------------------------------------------------------------------------
 # adversarial route tables: latency-only, never results
 # ---------------------------------------------------------------------------
@@ -103,6 +126,7 @@ _ADVERSARIAL = [
                       pallas_min_queries=1 << 30),       # everything -> loop
     RouteTable.single(pallas_max_nodes=1),               # kernel "never fits"
     RouteTable.single(bf_max_work=0, pallas_max_capacity=0),
+    RouteTable.tpu_default(),                            # TPU built-in rule
 ]
 
 

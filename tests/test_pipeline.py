@@ -270,3 +270,26 @@ def test_updates_coalesce_to_newest_values():
         st = pipe.stats()
         assert pipe.store.get("default").bvh.size() == 160
         assert st.swap_count == 2         # first update + the coalesced one
+
+
+def test_failed_update_keeps_its_exception_in_stats():
+    """A maintenance failure is counted AND kept: stats() carries the
+    exception so a caller (chip_smoke.py) can re-raise it, and it is
+    visible as soon as wait_maintenance_idle returns."""
+    with _pipeline() as pipe:
+        boom = RuntimeError("refit exploded")
+
+        def failing_update(name, values):
+            raise boom
+
+        pipe.store.update = failing_update
+        assert pipe.stats().last_maintenance_error is None
+        pipe.update_index("default", G.Points(jnp.asarray(_pts(300, 9))))
+        assert pipe.wait_maintenance_idle(60.0)
+        st = pipe.stats()
+        assert st.maintenance_errors == 1
+        assert st.last_maintenance_error is boom
+        assert st.swap_count == 0
+        # serving is unaffected: the live version is still the build
+        r = pipe.submit(knn_request(_pts(2, 10), k=2)).result(60.0)
+        assert r.stats.index_version == 1
