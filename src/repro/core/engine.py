@@ -442,10 +442,6 @@ class QueryEngine:
             out = sp.fence(fn(*args))
         return out, sp.dur_us
 
-    def _route_span(self, op: str):
-        """Span around a route-table decision (wall clock, Python-only)."""
-        return TEL.span("engine.route", op=op)
-
     def _cached(self, key, make):
         # locked: concurrent server threads must not compile the same key
         # twice or lose stats increments (IndexStore promises this level of
@@ -481,9 +477,7 @@ class QueryEngine:
         Returns ((counts, idx_buf), ExecInfo): FULL per-query counts plus the
         first `capacity` matched original indices per query (-1 padded).
         """
-        with self._route_span("spatial") as rsp:
-            route = self.route_spatial(bvh, predicates, capacity)
-            rsp.annotate(route=route)
+        route = self.route_spatial(bvh, predicates, capacity)
         bq = self._rule("spatial", bvh, None).block_q
         # every value a traced body closes over is named IN the key —
         # reprolint TRC004 pins this (a closed-over value missing from the
@@ -542,9 +536,7 @@ class QueryEngine:
 
     def exec_knn(self, bvh, predicates):
         """Cached kNN for a Nearest bucket. Returns ((dists, idxs), ExecInfo)."""
-        with self._route_span("knn") as rsp:
-            route = self.route_knn(bvh, predicates)
-            rsp.annotate(route=route)
+        route = self.route_knn(bvh, predicates)
         k = predicates.k
         bq = self._rule("knn", bvh, None).block_q
         getter = bvh._getter

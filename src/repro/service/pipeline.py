@@ -474,6 +474,7 @@ class ServingPipeline:
         return None, None, wait
 
     def _run_scheduler(self):
+        seq = 0                     # batch sequence number, for the spans
         while True:
             with self._cv:
                 while True:
@@ -486,16 +487,26 @@ class ServingPipeline:
                     # extra is seconds until the earliest forced close (or
                     # None when idle); clamp so a just-passed deadline
                     # doesn't busy-spin
-                    self._cv.wait(None if extra is None else max(extra, 1e-4))
-            self._dispatch(key, taken, extra, time.perf_counter())
+                    with TEL.span("pipeline.wait", batch=seq + 1):
+                        self._cv.wait(None if extra is None
+                                      else max(extra, 1e-4))
+            seq += 1
+            self._dispatch(key, taken, extra, time.perf_counter(), seq)
 
     def _dispatch(self, key: tuple, tickets: list[Ticket], reason: str,
-                  t_picked: float | None = None):
+                  t_picked: float | None = None, seq: int = 0):
         """Outside the lock: pin -> execute -> scatter -> account.
         `t_picked` is when the scheduler pulled the group off its queue —
-        the queue/batch phase boundary in the request's span tree."""
-        with TEL.span("pipeline.dispatch", reason=reason,
-                      requests=len(tickets)) as dsp:
+        the queue/batch phase boundary in the request's span tree.
+
+        The batch runs as flat phase spans ``pipeline.<phase>``, each
+        with ``batch=seq`` (DESIGN.md §10): ``dispatch`` (plan, pin, the
+        launch of every device stage), then, switched inside
+        ``execute_group``, ``device_wait``, ``copy_back`` and ``deliver``
+        (scatter, tickets completed, accounting)."""
+        with TEL.phases("pipeline", batch=seq):
+            dsp = TEL.phase("dispatch", reason=reason,
+                            requests=len(tickets))
             group = self.batcher.plan([t.request for t in tickets])[0]
             dsp.annotate(index=group.index, bucket=group.bucket)
             t_disp = time.perf_counter()
@@ -525,29 +536,30 @@ class ServingPipeline:
                 self.store.release(entry)
             t_done = time.perf_counter()
 
-        service_us = (t_done - t_disp) * 1e6
-        tracer = TEL.get_tracer() if TEL.enabled() else None
-        missed = 0
-        for rid, ticket in enumerate(tickets):
-            resp = responses[rid]
-            total_us = (t_done - ticket.t_submit) * 1e6
-            late = (ticket.deadline_us is not None
-                    and total_us > ticket.deadline_us)
-            missed += late
-            phases = self._phase_breakdown(ticket, t_picked, t_disp,
-                                           service_us, resp.stats.kernel_us)
-            span_id = 0
-            if tracer is not None:
-                span_id = self._emit_request_spans(tracer, ticket, phases,
-                                                   t_done, late)
-            stats = dataclasses.replace(
-                resp.stats,
-                queue_wait_us=(t_disp - ticket.t_submit) * 1e6,
-                service_us=service_us, deadline_us=ticket.deadline_us,
-                deadline_missed=late, phase_us=phases, span_id=span_id)
-            ticket._complete(dataclasses.replace(resp, stats=stats))
+            service_us = (t_done - t_disp) * 1e6
+            tracer = TEL.get_tracer() if TEL.enabled() else None
+            missed = 0
+            for rid, ticket in enumerate(tickets):
+                resp = responses[rid]
+                total_us = (t_done - ticket.t_submit) * 1e6
+                late = (ticket.deadline_us is not None
+                        and total_us > ticket.deadline_us)
+                missed += late
+                phases = self._phase_breakdown(
+                    ticket, t_picked, t_disp, service_us,
+                    resp.stats.kernel_us)
+                span_id = 0
+                if tracer is not None:
+                    span_id = self._emit_request_spans(
+                        tracer, ticket, phases, t_done, late)
+                stats = dataclasses.replace(
+                    resp.stats,
+                    queue_wait_us=(t_disp - ticket.t_submit) * 1e6,
+                    service_us=service_us, deadline_us=ticket.deadline_us,
+                    deadline_missed=late, phase_us=phases, span_id=span_id)
+                ticket._complete(dataclasses.replace(resp, stats=stats))
 
-        self._account(key, group, tickets, reason, service_us, missed)
+            self._account(key, group, tickets, reason, service_us, missed)
 
     @staticmethod
     def _phase_breakdown(ticket: Ticket, t_picked: float, t_disp: float,

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,7 +27,7 @@ from .batcher import (KIND_KNN, KIND_RAY, KIND_WITHIN, Batcher, Group,
 from .index_store import IndexStore, IndexVersion
 
 __all__ = ["ServiceConfig", "RequestStats", "Response", "QueryServer",
-           "execute_group"]
+           "Launched", "launch_group", "finish_group", "execute_group"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,18 +81,28 @@ class Response:
     overflow: bool = False
 
 
-def execute_group(engine: E.QueryEngine, config: ServiceConfig,
-                  entry: IndexVersion, group: Group) -> dict[int, "Response"]:
-    """Dispatch ONE planned group against a pinned index version and scatter
-    the bucket results back to per-request Responses (keyed by the request
-    ids recorded in ``group.members``). Shared by the synchronous
-    ``QueryServer.handle`` and the async ``ServingPipeline`` — the caller
-    owns version pinning and any timing bookkeeping."""
+@dataclasses.dataclass(frozen=True)
+class Launched:
+    """A group whose device work is enqueued but not awaited: the bucket's
+    result arrays, still on the device — (counts, idxs) for within,
+    (dists, idxs) otherwise — and the stats every member's Response
+    carries. :func:`finish_group` completes it."""
+    group: Group
+    arrays: tuple
+    stats: RequestStats
+    capacity: int         # within: counts above it flag overflow
+
+
+def launch_group(engine: E.QueryEngine, config: ServiceConfig,
+                 entry: IndexVersion, group: Group) -> Launched:
+    """Enqueue ONE planned group's device work against a pinned index
+    version and return without waiting for it (a sharded version hands
+    the group to its mesh's ``ShardedExecutor``)."""
     if getattr(entry, "sharded", False):
         # sharded versions carry their mesh's ShardedExecutor: collective
         # staged dispatch replaces the engine's single-device executable
         # cache (duck-typed so this module never imports service.sharded)
-        return entry.executor.execute_group(config, entry, group)
+        return entry.executor.launch_group(config, entry, group)
     bvh = entry.bvh
     with TEL.span("server.execute_group", kind=group.kind,
                   bucket=group.bucket, index=entry.name,
@@ -103,58 +114,73 @@ def execute_group(engine: E.QueryEngine, config: ServiceConfig,
         tiny = bvh.tree is None
         info = E.ExecInfo(E.ROUTE_LOOP, False) if tiny else None
 
-        overflow_rows = None
         if group.kind == KIND_WITHIN:
             preds = P.intersects(G.Spheres(a, jnp.asarray(group.b)))
             if tiny:
-                counts, buf = bvh._fill_impl(preds, config.capacity,
-                                             bvh.policy)
+                arrays = bvh._fill_impl(preds, config.capacity, bvh.policy)
             else:
-                (counts, buf), info = engine.exec_spatial(
-                    bvh, preds, config.capacity)
-            # CSR assembly: device buffers -> host arrays + overflow flags
-            with TEL.span("server.assemble", kind=group.kind):
-                counts, buf = np.asarray(counts), np.asarray(buf)
-                overflow_rows = counts > config.capacity
-            res_rows = (counts, buf)
+                arrays, info = engine.exec_spatial(bvh, preds,
+                                                   config.capacity)
         elif group.kind == KIND_KNN:
             preds = P.nearest(G.Points(a), k=group.k)
             if tiny:
                 res = bvh.query(preds)
-                d, i = res.distances, res.indices
+                arrays = (res.distances, res.indices)
             else:
-                (d, i), info = engine.exec_knn(bvh, preds)
-            with TEL.span("server.assemble", kind=group.kind):
-                res_rows = (np.asarray(d), np.asarray(i))
+                arrays, info = engine.exec_knn(bvh, preds)
         else:  # KIND_RAY
             rays = G.Rays(a, jnp.asarray(group.b))
             if tiny:
                 res = bvh.query(P.RayNearest(rays, group.k))
-                d, i = res.distances, res.indices
+                arrays = (res.distances, res.indices)
             else:
-                (d, i), info = engine.exec_ray_nearest(bvh, rays, group.k)
-            with TEL.span("server.assemble", kind=group.kind):
-                res_rows = (np.asarray(d), np.asarray(i))
+                arrays, info = engine.exec_ray_nearest(bvh, rays, group.k)
+    stats = RequestStats(kind=group.kind, route=info.route,
+                         bucket=group.bucket, index_name=entry.name,
+                         index_version=entry.version,
+                         cache_hit=info.cache_hit, kernel_us=info.kernel_us)
+    return Launched(group, tuple(arrays), stats, config.capacity)
 
-        out: dict[int, Response] = {}
-        with TEL.span("server.scatter", requests=len(group.members)):
+
+def finish_group(launched: Launched) -> dict[int, "Response"]:
+    """Wait for a launched group's device work, copy its results to the
+    host and scatter them to per-request Responses (keyed by the request
+    ids recorded in ``group.members``). Each step switches the calling
+    thread's phase (``device_wait``, ``copy_back``, ``deliver``) when it
+    runs a ``telemetry.phases`` block, as the pipeline's scheduler does."""
+    group = launched.group
+    TEL.phase("device_wait")
+    jax.block_until_ready(launched.arrays)
+    TEL.phase("copy_back")
+    # CSR assembly: device buffers -> host arrays (+ overflow flags)
+    with TEL.span("server.assemble", kind=group.kind):
+        first, idxs = (np.asarray(x) for x in launched.arrays)
+    TEL.phase("deliver")
+    out: dict[int, Response] = {}
+    with TEL.span("server.scatter", requests=len(group.members)):
+        if group.kind == KIND_WITHIN:
+            over = first > launched.capacity
             for rid, start, m in group.members:
-                stats = RequestStats(kind=group.kind, route=info.route,
-                                     bucket=group.bucket,
-                                     index_name=entry.name,
-                                     index_version=entry.version,
-                                     cache_hit=info.cache_hit,
-                                     kernel_us=info.kernel_us)
                 sl = slice(start, start + m)
-                if group.kind == KIND_WITHIN:
-                    counts, buf = res_rows
-                    out[rid] = Response(
-                        stats, counts=counts[sl], idxs=buf[sl],
-                        overflow=bool(overflow_rows[sl].any()))
-                else:
-                    d, i = res_rows
-                    out[rid] = Response(stats, dists=d[sl], idxs=i[sl])
+                out[rid] = Response(launched.stats, counts=first[sl],
+                                    idxs=idxs[sl],
+                                    overflow=bool(over[sl].any()))
+        else:
+            for rid, start, m in group.members:
+                sl = slice(start, start + m)
+                out[rid] = Response(launched.stats, dists=first[sl],
+                                    idxs=idxs[sl])
     return out
+
+
+def execute_group(engine: E.QueryEngine, config: ServiceConfig,
+                  entry: IndexVersion, group: Group) -> dict[int, "Response"]:
+    """Dispatch ONE planned group against a pinned index version and scatter
+    the bucket results back to per-request Responses: :func:`launch_group`
+    then :func:`finish_group`. Shared by the synchronous
+    ``QueryServer.handle`` and the async ``ServingPipeline`` — the caller
+    owns version pinning and any timing bookkeeping."""
+    return finish_group(launch_group(engine, config, entry, group))
 
 
 class QueryServer:

@@ -11,13 +11,14 @@ the serving-side counterpart of ArborX's distributed tree (§2.3):
     re-exchange of the per-shard top bounds, and everything lands through
     the inherited atomic version swap / ``pin``/``release``/``pinned``
     refcounting — serving never stalls behind maintenance.
-  * :class:`ShardedExecutor` — the group dispatcher ``execute_group``
+  * :class:`ShardedExecutor` — the group launcher ``launch_group``
     routes to whenever a batch names a sharded index: predicates are
     all-gathered, every shard answers against local data, partial results
     ``all_to_all`` back to the originating shard and merge (top-k by
     distance, psum for counts). Each phase is a separately-jitted
     ``shard_map`` stage so telemetry can fence and attribute device time
-    to gather / local-traverse / exchange / merge.
+    to gather / local-traverse / exchange / merge (unfenced under a
+    profiler alone, each stage's span times its enqueue).
 
 Refit quality is monitored PER SHARD: drift is rarely uniform, so the
 store tracks an (R,)-tuple of SAH costs and a single shard degrading past
@@ -50,7 +51,7 @@ from ..core.index import _bcast_state
 from ..telemetry import tracer as TEL
 from .batcher import KIND_KNN, KIND_WITHIN, Group, _pad_edge
 from .index_store import IndexStore
-from .server import RequestStats, Response, ServiceConfig
+from .server import Launched, RequestStats, ServiceConfig
 
 __all__ = ["ShardedExecutor", "ShardedIndexStore", "ShardedIndexVersion"]
 
@@ -72,7 +73,7 @@ class ShardedIndexVersion:
     refits_since_build: int
     executor: "ShardedExecutor" = dataclasses.field(repr=False)
 
-    #: duck-typed routing flag read by ``server.execute_group``
+    #: duck-typed routing flag read by ``server.launch_group``
     sharded = True
 
     @property
@@ -98,7 +99,7 @@ class _StagePlan:
 
 
 class ShardedExecutor:
-    """Executes planned :class:`~repro.service.batcher.Group` batches
+    """Launches planned :class:`~repro.service.batcher.Group` batches
     against a pinned :class:`ShardedIndexVersion` as a four-stage
     collective pipeline. Staging exists for ATTRIBUTION: when telemetry is
     enabled each stage is device-fenced under its own span, so the report
@@ -232,11 +233,12 @@ class ShardedExecutor:
         return _StagePlan(gather, local, exchange, merge)
 
     # -- dispatch ------------------------------------------------------------
-    def execute_group(self, config: ServiceConfig, entry: ShardedIndexVersion,
-                      group: Group) -> dict[int, Response]:
-        """Serve ONE planned group against a pinned sharded version and
-        scatter bucket results to per-request Responses — the sharded
-        counterpart of ``server.execute_group`` (which routes here)."""
+    def launch_group(self, config: ServiceConfig, entry: ShardedIndexVersion,
+                     group: Group) -> Launched:
+        """Enqueue ONE planned group's four stages against a pinned
+        sharded version — the sharded counterpart of
+        ``server.launch_group`` (which routes here); ``server.finish_group``
+        waits for the merged answers, copies them back and scatters them."""
         tree = entry.tree
         R = self.R
         # shard_map needs the batch divisible by R; buckets are powers of
@@ -267,27 +269,11 @@ class ShardedExecutor:
             with TEL.span("sharded.merge", kind=group.kind) as sp:
                 merged = sp.fence(plan.merge(*exchanged))
             kernel_us += sp.dur_us
-
-            out: dict[int, Response] = {}
-            with TEL.span("server.scatter", requests=len(group.members)):
-                stats = RequestStats(
-                    kind=group.kind, route="sharded", bucket=group.bucket,
-                    index_name=entry.name, index_version=entry.version,
-                    cache_hit=warm, kernel_us=kernel_us)
-                if group.kind == KIND_WITHIN:
-                    counts, buf = (np.asarray(x) for x in merged)
-                    over = counts > config.capacity
-                    for rid, start, m in group.members:
-                        sl = slice(start, start + m)
-                        out[rid] = Response(
-                            stats, counts=counts[sl], idxs=buf[sl],
-                            overflow=bool(over[sl].any()))
-                else:
-                    d, i = (np.asarray(x) for x in merged)
-                    for rid, start, m in group.members:
-                        sl = slice(start, start + m)
-                        out[rid] = Response(stats, dists=d[sl], idxs=i[sl])
-        return out
+        stats = RequestStats(
+            kind=group.kind, route="sharded", bucket=group.bucket,
+            index_name=entry.name, index_version=entry.version,
+            cache_hit=warm, kernel_us=kernel_us)
+        return Launched(group, tuple(merged), stats, config.capacity)
 
 
 # -- shard-local maintenance steps (cached: jit reuses warm executables

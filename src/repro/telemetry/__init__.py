@@ -11,20 +11,22 @@ Quickstart::
     # then load trace.json at https://ui.perfetto.dev
 
 Disabled (the default unless REPRO_TELEMETRY=1) every ``span()`` site
-costs one flag check and returns the shared no-op ``NULL_SPAN``.
+costs one flag check and one ``jax.profiler.TraceAnnotation.is_enabled()``
+check: while a JAX profiler records, the span is a host event in its
+trace (never fenced), else the shared no-op ``NULL_SPAN``.
 """
 from .tracer import (NULL_SPAN, Span, Tracer, disable, enable, enabled,
-                     get_tracer, set_tracer, span)
-from .metrics import Counter, Gauge, Histogram, MetricsRegistry
+                     get_tracer, phase, phases, set_tracer, span)
+from .metrics import Counter, Gauge, MetricsRegistry
 from .export import (chrome_trace_events, read_metrics_jsonl,
                      summarize_spans, validate_chrome_trace,
                      validate_metrics_lines, write_chrome_trace,
                      write_metrics_jsonl)
 
 __all__ = [
-    "NULL_SPAN", "Span", "Tracer", "span", "enable", "disable", "enabled",
-    "get_tracer", "set_tracer",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "NULL_SPAN", "Span", "Tracer", "span", "phases", "phase", "enable",
+    "disable", "enabled", "get_tracer", "set_tracer",
+    "Counter", "Gauge", "MetricsRegistry",
     "chrome_trace_events", "write_chrome_trace", "validate_chrome_trace",
     "write_metrics_jsonl", "read_metrics_jsonl", "validate_metrics_lines",
     "summarize_spans",

@@ -1,4 +1,4 @@
-"""Metrics registry: counters, gauges, log-bucket histograms (DESIGN.md §10).
+"""Metrics registry: counters and gauges (DESIGN.md §10).
 
 The registry replaces the repo's ad-hoc stats islands (``EngineStats``,
 ``PipelineStats``) with one vocabulary:
@@ -7,11 +7,7 @@ The registry replaces the repo's ad-hoc stats islands (``EngineStats``,
   * :class:`Gauge`    — instantaneous level with an atomically-tracked
     high-water mark (``adjust``/``set`` update value AND high under the
     registry lock, so a concurrent reader can never observe a level
-    above the recorded high — the queue-depth bug class);
-  * :class:`Histogram`— fixed log-scale buckets: ``observe`` costs one
-    ``log``-free bisect, p50/p99 come straight off the bucket counts,
-    and NO samples are ever stored, so a week of serving costs the same
-    memory as a minute.
+    above the recorded high — the queue-depth bug class).
 
 All mutation goes through one registry-level lock: metric updates are a
 few nanoseconds of bookkeeping, never device syncs, so a shared lock is
@@ -20,11 +16,9 @@ across every metric at once.
 """
 from __future__ import annotations
 
-import bisect
-import math
 import threading
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry"]
+__all__ = ["Counter", "Gauge", "MetricsRegistry"]
 
 
 class Counter:
@@ -99,84 +93,6 @@ class Gauge:
         return {"type": "gauge", "value": self._value, "high": self._high}
 
 
-class Histogram:
-    """Fixed log-scale buckets over (0, inf).
-
-    Bucket upper edges form a geometric ladder from `lo` to `hi` with
-    `per_decade` buckets per factor of 10 (plus an underflow bucket
-    below `lo` and an overflow bucket above `hi`). Quantiles interpolate
-    within the containing bucket, so p50/p99 are exact to one bucket
-    width (~±12% at the default 8/decade) with zero sample storage.
-    """
-
-    __slots__ = ("name", "_lock", "edges", "counts", "_n", "_sum")
-
-    def __init__(self, name: str, lock: threading.Lock, *,
-                 lo: float = 1.0, hi: float = 1e8, per_decade: int = 8):
-        if not (lo > 0 and hi > lo and per_decade >= 1):
-            raise ValueError("need 0 < lo < hi and per_decade >= 1")
-        self.name = name
-        self._lock = lock
-        n = int(math.ceil(math.log10(hi / lo) * per_decade))
-        ratio = 10.0 ** (1.0 / per_decade)
-        self.edges = [lo * ratio ** i for i in range(n + 1)]   # upper edges
-        self.counts = [0] * (n + 2)                            # +under/over
-        self._n = 0
-        self._sum = 0.0
-
-    def observe(self, x: float):
-        # counts[0] = underflow (x <= lo); counts[j] covers
-        # (edges[j-1], edges[j]]; counts[-1] = overflow (x > hi)
-        i = 0 if x <= self.edges[0] else \
-            min(bisect.bisect_left(self.edges, x), len(self.counts) - 1)
-        with self._lock:
-            self.counts[i] += 1
-            self._n += 1
-            self._sum += x
-
-    @property
-    def count(self) -> int:
-        return self._n
-
-    @property
-    def total(self) -> float:
-        return self._sum
-
-    @property
-    def mean(self) -> float:
-        return self._sum / self._n if self._n else 0.0
-
-    def quantile(self, q: float) -> float:
-        """Approximate q-quantile from bucket counts (upper-edge linear
-        interpolation; underflow reports `lo`, overflow `hi`)."""
-        if not 0.0 <= q <= 1.0:
-            raise ValueError(f"q={q} outside [0, 1]")
-        with self._lock:
-            n, counts = self._n, list(self.counts)
-        if n == 0:
-            return 0.0
-        rank = q * n
-        acc = 0.0
-        for i, c in enumerate(counts):
-            if acc + c >= rank and c > 0:
-                if i == 0:
-                    return self.edges[0]
-                if i == len(counts) - 1:
-                    return self.edges[-1]
-                lo_edge = self.edges[i - 1]
-                hi_edge = self.edges[i]
-                frac = (rank - acc) / c
-                return lo_edge + (hi_edge - lo_edge) * min(max(frac, 0.0), 1.0)
-            acc += c
-        return self.edges[-1]
-
-    def to_dict(self) -> dict:
-        return {"type": "histogram", "count": self._n, "sum": self._sum,
-                "p50": self.quantile(0.5), "p90": self.quantile(0.9),
-                "p99": self.quantile(0.99),
-                "buckets": {"edges": self.edges, "counts": list(self.counts)}}
-
-
 class MetricsRegistry:
     """Named get-or-create metric registry; one lock for all mutation."""
 
@@ -184,11 +100,11 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._metrics: dict = {}
 
-    def _get(self, name: str, cls, **kw):
+    def _get(self, name: str, cls):
         with self._lock:
             m = self._metrics.get(name)
             if m is None:
-                m = self._metrics[name] = cls(name, self._lock, **kw)
+                m = self._metrics[name] = cls(name, self._lock)
             elif not isinstance(m, cls):
                 raise TypeError(f"metric {name!r} already registered as "
                                 f"{type(m).__name__}, not {cls.__name__}")
@@ -199,9 +115,6 @@ class MetricsRegistry:
 
     def gauge(self, name: str) -> Gauge:
         return self._get(name, Gauge)
-
-    def histogram(self, name: str, **kw) -> Histogram:
-        return self._get(name, Histogram, **kw)
 
     def names(self) -> list:
         with self._lock:

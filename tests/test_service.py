@@ -91,6 +91,42 @@ def test_batcher_rejects_bad_requests():
 # server results == direct BVH queries
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("kind", ["knn", "within", "ray"])
+def test_execute_group_is_launch_then_finish(kind):
+    """``execute_group`` is ``launch_group`` then ``finish_group``: the
+    launch hands back device arrays without waiting, the finish copies
+    them back and scatters the same Responses."""
+    import jax
+
+    from repro.service.server import (execute_group, finish_group,
+                                      launch_group)
+    srv = _server(300, seed=12, capacity=8)
+    qs = [_pts(5, 13), _pts(9, 14)]
+    dirs = np.random.default_rng(15).normal(size=(9, DIM))
+    reqs = {"knn": [knn_request(q, k=3) for q in qs],
+            "within": [within_request(q, 0.2) for q in qs],
+            "ray": [ray_request(qs[0], dirs[:5], k=2),
+                    ray_request(qs[1], dirs, k=2)]}[kind]
+    (group,) = srv.batcher.plan(reqs)
+    with srv.store.pinned("default") as entry:
+        execute_group(srv.engine, srv.config, entry, group)    # warm
+        launched = launch_group(srv.engine, srv.config, entry, group)
+        assert all(isinstance(x, jax.Array) for x in launched.arrays)
+        a = finish_group(launched)
+        b = execute_group(srv.engine, srv.config, entry, group)
+    assert sorted(a) == sorted(b) == [0, 1]
+    for rid in a:
+        assert a[rid].stats == b[rid].stats == launched.stats
+        assert a[rid].overflow == b[rid].overflow
+        for field in ("dists", "idxs", "counts"):
+            x, y = getattr(a[rid], field), getattr(b[rid], field)
+            assert (x is None and y is None) or np.array_equal(x, y)
+    if kind == "within":
+        assert a[1].counts.shape == (9,) and a[1].idxs.shape == (9, 8)
+    else:
+        assert a[1].dists.shape == (9, 3 if kind == "knn" else 2)
+
+
 def test_server_scatter_matches_direct_queries():
     pts = _pts(400, seed=2)
     srv = QueryServer(config=ServiceConfig(capacity=64))

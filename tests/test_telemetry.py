@@ -196,6 +196,137 @@ def test_enable_disable_toggles_and_swaps_rings():
 
 
 # ---------------------------------------------------------------------------
+# the profiler sink and phase runs
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def profiler(tmp_path):
+    """Record a JAX profiler trace into tmp_path; the returned function
+    stops it and gives the host events as {name: [stats dict, ...]}."""
+    import glob
+    import os
+
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    running = [True]
+
+    def stop():
+        jax.profiler.stop_trace()
+        running[0] = False
+        (path,) = glob.glob(os.path.join(str(tmp_path), "plugins",
+                                         "profile", "*", "*.xplane.pb"))
+        events: dict = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        events.setdefault(e.name, []).append(
+                            {k: v for k, v in e.stats})
+        return events
+
+    yield stop
+    if running[0]:
+        jax.profiler.stop_trace()
+
+
+def test_profiled_span_lands_on_a_host_plane_with_its_args(
+        telemetry_disabled, profiler):
+    with telemetry.span("probe.profiled", batch=7, kind="knn") as sp:
+        sp.annotate(bucket=64)
+    assert len(telemetry.get_tracer()) == 0     # the ring stays off
+    (stats,) = profiler()["probe.profiled"]
+    assert stats == {"batch": 7, "kind": "knn", "bucket": 64}
+
+
+def test_profiled_span_never_fences(telemetry_disabled, profiler,
+                                    monkeypatch):
+    import jax
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(x) or real(x))
+    value = jnp.arange(8) * 2
+    with telemetry.span("probe.fence") as sp:
+        out = sp.fence(value)
+    assert out is value and calls == []
+    assert sp.span_id == 0 and sp.dur_us == 0.0
+    assert "probe.fence" in profiler()
+
+
+def test_span_with_both_sinks_is_in_the_ring_and_the_profile(
+        fresh_tracer, profiler):
+    with telemetry.span("probe.both", op="knn") as sp:
+        sp.fence(jnp.ones(4).sum())
+    (s,) = [s for s in fresh_tracer.drain() if s.name == "probe.both"]
+    assert s.clock == "device" and s.args == {"op": "knn"}
+    assert profiler()["probe.both"] == [{"op": "knn"}]
+
+
+def test_span_with_both_sinks_off_is_the_null_span(telemetry_disabled):
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
+    assert telemetry.span("probe.off", a=1) is telemetry.NULL_SPAN
+    assert telemetry.phase("dispatch") is telemetry.NULL_SPAN
+    with telemetry.phases("probe", batch=1):
+        assert telemetry.phase("dispatch") is telemetry.NULL_SPAN
+    assert len(telemetry.get_tracer()) == 0
+
+
+def test_phases_tile_a_thread_with_flat_spans(fresh_tracer):
+    with telemetry.phases("lane", batch=3):
+        telemetry.phase("a", x=1)
+        with telemetry.span("child"):
+            pass
+        telemetry.phase("b", y=2)
+        telemetry.phase("c")
+    assert telemetry.phase("d") is telemetry.NULL_SPAN   # block closed
+    spans = fresh_tracer.drain()
+    lane = [s for s in spans if s.name.startswith("lane.")]
+    assert [s.name for s in lane] == ["lane.a", "lane.b", "lane.c"]
+    assert all(s.parent_id == 0 and s.args["batch"] == 3 for s in lane)
+    assert lane[0].args["x"] == 1 and lane[1].args["y"] == 2
+    for prev, nxt in zip(lane, lane[1:]):
+        assert prev.t0_ns + prev.dur_ns <= nxt.t0_ns
+    (child,) = [s for s in spans if s.name == "child"]
+    assert child.parent_id == lane[0].span_id
+
+
+def test_scheduler_phases_are_flat_and_tile_each_batch(fresh_tracer):
+    with _pipeline(200, seed=72) as pipe:
+        for i in range(3):
+            pipe.submit(knn_request(_pts(3, 73 + i), k=2),
+                        deadline_us=1_000.0).result(60.0)
+    spans = fresh_tracer.drain()
+    names = ("pipeline.dispatch", "pipeline.device_wait",
+             "pipeline.copy_back", "pipeline.deliver")
+    sched = sorted((s for s in spans if s.name in names
+                    or s.name == "pipeline.wait"), key=lambda s: s.t0_ns)
+    assert all(s.parent_id == 0 for s in sched)
+    for prev, nxt in zip(sched, sched[1:]):
+        assert prev.t0_ns + prev.dur_ns <= nxt.t0_ns
+    batches = [s for s in sched if s.name != "pipeline.wait"]
+    assert [s.name for s in batches] == list(names) * 3
+    assert [s.args["batch"] for s in batches] == [1] * 4 + [2] * 4 + [3] * 4
+    by_id = {s.span_id: s for s in spans}
+
+    def phase_of(s):
+        while s.parent_id:
+            s = by_id[s.parent_id]
+        return s.name
+
+    for name, phase in (("server.execute_group", "pipeline.dispatch"),
+                        ("engine.kernel", "pipeline.dispatch"),
+                        ("server.assemble", "pipeline.copy_back"),
+                        ("server.scatter", "pipeline.deliver")):
+        kids = [s for s in spans if s.name == name
+                and s.tid == "repro-pipeline-scheduler"]
+        assert len(kids) == 3
+        assert all(phase_of(s) == phase for s in kids), name
+
+
+# ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
 
@@ -255,36 +386,6 @@ def test_gauge_high_water_survives_drains():
     g.note_high(high - 1)
     assert g.high == high
     assert g.to_dict() == {"type": "gauge", "value": 0, "high": high}
-
-
-def test_histogram_quantiles_from_log_buckets():
-    reg = MetricsRegistry()
-    h = reg.histogram("latency_us")
-    vals = list(range(1, 1001))               # 1..1000 us, uniform
-    for v in vals:
-        h.observe(float(v))
-    assert h.count == 1000
-    assert h.total == pytest.approx(sum(vals))
-    assert h.mean == pytest.approx(500.5)
-    # one-bucket accuracy: +-12% at the default 8 buckets/decade
-    assert h.quantile(0.5) == pytest.approx(500, rel=0.15)
-    assert h.quantile(0.99) == pytest.approx(990, rel=0.15)
-    assert h.quantile(0.0) <= h.quantile(0.5) <= h.quantile(1.0)
-    with pytest.raises(ValueError, match="outside"):
-        h.quantile(1.5)
-
-
-def test_histogram_underflow_overflow_buckets():
-    reg = MetricsRegistry()
-    h = reg.histogram("h", lo=1.0, hi=100.0, per_decade=4)
-    h.observe(0.01)                           # underflow
-    h.observe(1e9)                            # overflow
-    assert h.counts[0] == 1 and h.counts[-1] == 1
-    assert sum(h.counts) == h.count == 2
-    d = h.to_dict()
-    assert len(d["buckets"]["counts"]) == len(d["buckets"]["edges"]) + 1
-    with pytest.raises(ValueError, match="per_decade"):
-        reg.histogram("bad", lo=10.0, hi=1.0)
 
 
 def test_registry_get_or_create_and_type_collision():
@@ -363,30 +464,21 @@ def test_metrics_jsonl_round_trip(tmp_path):
     g = reg.gauge("g")
     g.adjust(+2)
     g.adjust(-1)
-    h = reg.histogram("h")
-    for v in (10.0, 100.0, 1000.0):
-        h.observe(v)
     path = str(tmp_path / "metrics.jsonl")
-    assert write_metrics_jsonl(path, reg) == 3
+    assert write_metrics_jsonl(path, reg) == 2
     back = read_metrics_jsonl(path)
     assert validate_metrics_lines(back) == []
     assert back["c"]["value"] == 3
     assert (back["g"]["value"], back["g"]["high"]) == (1, 2)
-    assert back["h"]["count"] == 3
-    assert sum(back["h"]["buckets"]["counts"]) == 3
 
 
 def test_validate_metrics_rejects_malformed():
     assert validate_metrics_lines({"x": {"type": "counter"}}) != []
     assert validate_metrics_lines({"x": {"type": "gauge", "value": 1}}) != []
     assert validate_metrics_lines({"x": {"type": "nope"}}) != []
-    short = {"type": "histogram", "count": 1,
-             "buckets": {"edges": [1.0, 2.0], "counts": [0, 1]}}
-    assert any("len(counts)" in p
-               for p in validate_metrics_lines({"h": short}))
-    drift = {"type": "histogram", "count": 5,
-             "buckets": {"edges": [1.0, 2.0], "counts": [0, 1, 1]}}
-    assert any("sum" in p for p in validate_metrics_lines({"h": drift}))
+    assert validate_metrics_lines(
+        {"c": {"type": "counter", "value": 2},
+         "g": {"type": "gauge", "value": 1, "high": 3}}) == []
 
 
 def test_summarize_spans_aggregates_per_name():
@@ -455,7 +547,7 @@ def test_serving_span_taxonomy_reaches_the_kernel(fresh_tracer):
     names = {s.name for s in fresh_tracer.drain()}
     for expected in ("pipeline.submit", "pipeline.dispatch",
                      "server.execute_group", "server.assemble",
-                     "server.scatter", "engine.route", "engine.kernel",
+                     "server.scatter", "engine.kernel",
                      "store.build", "request", "request.kernel"):
         assert expected in names, f"missing span {expected!r}"
 
