@@ -38,6 +38,7 @@ from ..kernels.bvh_callback import bvh_traverse_callback
 from ..kernels.bvh_traverse import bvh_traverse_knn, bvh_traverse_spatial
 from ..telemetry import tracer as TEL
 from . import geometry as G
+from . import lbvh
 from . import predicates as P
 from . import route_table as RT
 
@@ -431,13 +432,15 @@ class QueryEngine:
         with self._cache_lock:
             self.stats.jit_traces += 1
 
-    def _launch(self, fn, args, *, route: str, op: str, hit: bool):
+    def _launch(self, fn, args, *, route: str, op: str, hit: bool,
+                **span_args):
         """Run a cached executable under an ``engine.kernel`` span and
         return (result, kernel_us). With telemetry enabled the span is
         device-fenced (block_until_ready), so kernel_us covers actual
         device execution; disabled, this is one flag check and the
         dispatch stays fully async (kernel_us = 0.0)."""
-        sp = TEL.span("engine.kernel", route=route, op=op, cache_hit=hit)
+        sp = TEL.span("engine.kernel", route=route, op=op, cache_hit=hit,
+                      **span_args)
         with sp:
             out = sp.fence(fn(*args))
         return out, sp.dur_us
@@ -531,7 +534,9 @@ class QueryEngine:
 
         fn, hit = self._cached(key, make)
         out, kus = self._launch(fn, (bvh.tree, bvh.values, predicates),
-                                route=ROUTE_LOOP, op="spatial", hit=hit)
+                                route=ROUTE_LOOP, op="spatial", hit=hit,
+                                node_record_lanes=lbvh.node_record_lanes(
+                                    bvh.tree.dim))
         return out, ExecInfo(ROUTE_LOOP, hit, kus)
 
     def exec_knn(self, bvh, predicates):
@@ -577,7 +582,9 @@ class QueryEngine:
 
         fn, hit = self._cached(key, make)
         out, kus = self._launch(fn, (bvh.tree, bvh.values, predicates),
-                                route=ROUTE_LOOP, op="knn", hit=hit)
+                                route=ROUTE_LOOP, op="knn", hit=hit,
+                                node_record_lanes=lbvh.node_record_lanes(
+                                    bvh.tree.dim))
         return out, ExecInfo(ROUTE_LOOP, hit, kus)
 
     def exec_ray_nearest(self, bvh, rays, k: int):
@@ -595,7 +602,9 @@ class QueryEngine:
 
         fn, hit = self._cached(key, make)
         out, kus = self._launch(fn, (bvh.tree, bvh.values, rays),
-                                route=ROUTE_LOOP, op="ray_nearest", hit=hit)
+                                route=ROUTE_LOOP, op="ray_nearest", hit=hit,
+                                node_record_lanes=lbvh.node_record_lanes(
+                                    bvh.tree.dim))
         return out, ExecInfo(ROUTE_LOOP, hit, kus)
 
 

@@ -18,6 +18,10 @@ programming model, so we build functionally:
   5. ropes                  -> closed form: rope(node covering [f,l]) =
                                right_child(split_owner(l)); split positions
                                are a bijection so this is one scatter+gather.
+  6. node table             -> every node's box, rope, range_last and child
+                               packed as one int32 record, records in
+                               lane-dense 128-lane rows: the traversal
+                               loop's one gather a step (``node_record``).
 
 Node numbering: internal 0..N-2 (root = 0), leaves N-1..2N-2
 (leaf node id = N-1 + sorted position). SENTINEL rope = -1.
@@ -34,11 +38,14 @@ import jax.numpy as jnp
 from . import morton as M
 from .geometry import Boxes
 
-__all__ = ["LBVH", "build", "refit", "refit_with_quality", "sah_cost"]
+__all__ = ["LBVH", "build", "node_record", "node_record_lanes", "refit",
+           "refit_with_quality", "sah_cost"]
 
 BUILD_ENGINES = ("auto", "pallas", "ref")
 
 SENTINEL = jnp.int32(-1)
+
+ROW_LANES = 128     # a TPU vector register row: the node table's minor width
 
 
 def _register(cls):
@@ -66,6 +73,9 @@ class LBVH:
     range_first: jax.Array  # (N-1,) int32 first sorted-leaf position per
                             # internal node — kept so ``refit`` can re-run the
                             # RMQ AABB pass without redoing the Karras search
+    node_table: jax.Array   # (rows, max(128, L)) int32: every node's record
+                            # of L lanes (see ``node_record``), what the
+                            # traversal loop reads — one row gather a step
 
     @property
     def num_leaves(self):
@@ -74,6 +84,75 @@ class LBVH:
     @property
     def dim(self):
         return self.node_lo.shape[-1]
+
+
+def node_record_lanes(dim: int) -> int:
+    """Lanes of one node record: the smallest power of two >= 2*dim + 3
+    (lo bits, hi bits, rope, range_last, child). 16 for 3-D boxes."""
+    return 1 << (2 * dim + 2).bit_length()
+
+
+def _box_bits(x):
+    """Float box coordinates -> their bit patterns as int32 lanes (exact:
+    -0.0, infinities and NaN payloads survive the round trip)."""
+    if x.dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    if x.dtype.itemsize > 4:
+        raise ValueError(f"node boxes of {x.dtype} do not fit int32 lanes")
+    uint = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(x, uint).astype(jnp.int32)
+
+
+def _box_from_bits(v, dtype):
+    if jnp.dtype(dtype).itemsize == 4:
+        return jax.lax.bitcast_convert_type(v, dtype)
+    uint = jnp.dtype(f"uint{8 * jnp.dtype(dtype).itemsize}")
+    return jax.lax.bitcast_convert_type(v.astype(uint), dtype)
+
+
+def _pack_nodes(node_lo, node_hi, rope, range_last, left_child, leaf_perm):
+    """The node table: ``rows = ceil(M / P)`` rows of ``P * L`` int32 lanes,
+    P = max(1, 128 // L) records to a row. Node i's record is lanes
+    [s*L, (s+1)*L) of row i % rows, s = i // rows: lo and hi (float bits),
+    rope, range_last and the child (``left_child`` for internal nodes,
+    ``leaf_perm`` for leaves). Rows are lane-dense, so the loop gathers
+    whole rows in the layout they are stored in; records are dealt to rows
+    in blocks of ``rows`` nodes so that packing is one 2-D transpose of a
+    field-major array, lane-dense on both sides (DESIGN.md §2)."""
+    m, dim = node_lo.shape
+    lanes = node_record_lanes(dim)
+    per_row = max(1, ROW_LANES // lanes)
+    rows = -(-m // per_row)
+    child = jnp.concatenate([left_child, leaf_perm])
+    fields = jnp.concatenate([
+        _box_bits(node_lo).T, _box_bits(node_hi).T,
+        jnp.stack([rope, range_last, child]),
+        jnp.zeros((lanes - 2 * dim - 3, m), jnp.int32)])      # (L, M)
+    fields = jnp.pad(fields, ((0, 0), (0, rows * per_row - m)))
+    return (fields.reshape(lanes, per_row, rows).transpose(1, 0, 2)
+            .reshape(per_row * lanes, rows).T)
+
+
+def node_record(tree: LBVH, node):
+    """Read node ``node``'s record: ``(lo, hi, rope, range_last, child)``.
+
+    One row gather from ``tree.node_table``; the record's lanes are picked
+    from the row by an exact integer select (every other record masked to
+    0 and summed away) and the box lanes bitcast back to the boxes' dtype,
+    so the values are bit-identical to ``node_lo[node]`` etc."""
+    dim = tree.dim
+    lanes = node_record_lanes(dim)
+    rows, width = tree.node_table.shape
+    per_row = width // lanes
+    row = tree.node_table[node % rows]
+    if per_row > 1:
+        mine = jnp.arange(per_row)[:, None] == node // rows
+        row = jnp.where(mine, row.reshape(per_row, lanes), 0).sum(
+            0, dtype=row.dtype)
+    dtype = tree.node_lo.dtype
+    return (_box_from_bits(row[:dim], dtype),
+            _box_from_bits(row[dim:2 * dim], dtype),
+            row[2 * dim], row[2 * dim + 1], row[2 * dim + 2])
 
 
 def _dkey(hi, lo, idx, i, j, n):
@@ -278,8 +357,19 @@ def _build_impl(boxes: Boxes, *, bits: int, refit: str, engine: str) -> LBVH:
     rope = jnp.where(range_last >= n - 1, SENTINEL,
                      right_child[split_owner[safe_last]]).astype(jnp.int32)
 
+    perm = perm.astype(jnp.int32)
     return LBVH(node_lo, node_hi, left_child, right_child, rope,
-                range_last, perm.astype(jnp.int32), first.astype(jnp.int32))
+                range_last, perm, first.astype(jnp.int32),
+                _pack_nodes(node_lo, node_hi, rope, range_last, left_child,
+                            perm))
+
+
+def _with_boxes(tree: LBVH, node_lo, node_hi) -> LBVH:
+    """``tree`` with new node boxes and the node table packed from them."""
+    return dataclasses.replace(
+        tree, node_lo=node_lo, node_hi=node_hi,
+        node_table=_pack_nodes(node_lo, node_hi, tree.rope, tree.range_last,
+                               tree.left_child, tree.leaf_perm))
 
 
 @jax.jit
@@ -306,10 +396,8 @@ def refit(tree: LBVH, boxes: Boxes) -> LBVH:
     leaf_hi = boxes.hi[tree.leaf_perm]
     int_lo, int_hi = _refit_rmq(leaf_lo, leaf_hi, tree.range_first,
                                 tree.range_last[:n - 1], max_log2)
-    return dataclasses.replace(
-        tree,
-        node_lo=jnp.concatenate([int_lo, leaf_lo], 0),
-        node_hi=jnp.concatenate([int_hi, leaf_hi], 0))
+    return _with_boxes(tree, jnp.concatenate([int_lo, leaf_lo], 0),
+                       jnp.concatenate([int_hi, leaf_hi], 0))
 
 
 @jax.jit
@@ -333,10 +421,8 @@ def refit_with_quality(tree: LBVH, boxes: Boxes) -> tuple[LBVH, jax.Array]:
                                 tree.range_last[:n - 1], max_log2)
     areas = _surface_measure(int_lo, int_hi)
     sah = jnp.sum(areas) / jnp.maximum(areas[0], jnp.finfo(areas.dtype).tiny)
-    new = dataclasses.replace(
-        tree,
-        node_lo=jnp.concatenate([int_lo, leaf_lo], 0),
-        node_hi=jnp.concatenate([int_hi, leaf_hi], 0))
+    new = _with_boxes(tree, jnp.concatenate([int_lo, leaf_lo], 0),
+                      jnp.concatenate([int_hi, leaf_hi], 0))
     return new, sah
 
 

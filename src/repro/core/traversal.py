@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from . import predicates as P
-from .lbvh import LBVH
+from .lbvh import LBVH, node_record
 
 __all__ = ["traverse", "traverse_knn", "value_at"]
 
@@ -60,27 +60,24 @@ def _traverse_one(tree: LBVH, values, pred, callback, state0, min_pos):
         node, state, done = carry
         is_leaf = node >= n - 1
         leaf_pos = node - (n - 1)
-        lo = tree.node_lo[node]
-        hi = tree.node_hi[node]
+        lo, hi, rope, range_last, child = node_record(tree, node)
 
         # subtree pruning: geometric overlap + pair-traversal position filter
         overlap = P.node_overlap_test(pred, lo[None, :], hi[None, :])[0]
-        pos_ok = tree.range_last[node] > min_pos
+        pos_ok = range_last > min_pos
         descend = overlap & pos_ok & ~is_leaf
 
         # leaf handling: fine test (§2.1.2 "fine nearest/fine search") + callback
-        safe_pos = jnp.clip(leaf_pos, 0, n - 1)
-        orig_idx = tree.leaf_perm[safe_pos]
+        orig_idx = jnp.where(is_leaf, child, 0)
         leaf_val = value_at(values, orig_idx)
         fine, cb_extra = _leaf_test(pred, leaf_val)
-        hit = is_leaf & overlap & fine & (safe_pos > min_pos)
+        hit = is_leaf & overlap & fine & (leaf_pos > min_pos)
 
         new_state, cb_done = callback(state, pred, leaf_val, orig_idx, cb_extra)
         state = tree_select(hit, new_state, state)
         done = done | (hit & cb_done)
 
-        lc = tree.left_child[jnp.clip(node, 0, n - 2)]
-        next_node = jnp.where(descend, lc, tree.rope[node])
+        next_node = jnp.where(descend, child, rope)
         return next_node, state, done
 
     _, state, _ = jax.lax.while_loop(cond, body, (root, state0, jnp.bool_(False)))
@@ -159,14 +156,12 @@ def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels):
         node, dists, idxs = carry
         tau = dists[k - 1]
         is_leaf = node >= n - 1
-        leaf_pos = jnp.clip(node - (n - 1), 0, n - 1)
-        lo = tree.node_lo[node]
-        hi = tree.node_hi[node]
+        lo, hi, rope, _, child = node_record(tree, node)
         mind = P.node_min_distance(pred, lo[None, :], hi[None, :])[0]
         promising = mind < tau
         descend = promising & ~is_leaf
 
-        orig_idx = tree.leaf_perm[leaf_pos]
+        orig_idx = jnp.where(is_leaf, child, 0)
         leaf_val = value_at(values, orig_idx)
         d = P.leaf_distance(pred, _as_batch1(leaf_val))[0]
         ok = is_leaf & promising & (d < tau)
@@ -176,8 +171,7 @@ def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels):
         dists2 = jnp.where(ok, nd, dists)
         idxs2 = jnp.where(ok, ni, idxs)
 
-        lc = tree.left_child[jnp.clip(node, 0, n - 2)]
-        next_node = jnp.where(descend, lc, tree.rope[node])
+        next_node = jnp.where(descend, child, rope)
         return next_node, dists2, idxs2
 
     dists0 = jnp.full((k,), big)

@@ -259,7 +259,9 @@ class ShardedExecutor:
                 gathered = sp.fence(plan.gather(*args))
             kernel_us += sp.dur_us
             with TEL.span("sharded.local_traverse", kind=group.kind,
-                          n_local=tree.n_local) as sp:
+                          n_local=tree.n_local,
+                          node_record_lanes=lbvh.node_record_lanes(
+                              tree.dim)) as sp:
                 partial = sp.fence(plan.local(tree.trees, tree.values,
                                               *gathered))
             kernel_us += sp.dur_us

@@ -8,6 +8,8 @@ worker given this file loads the TPU compiler. Kernels kept OFF the TPU
 route (a built-in rule, not a run-time fallback) carry a strict xfail
 with the compiler's message: the PR that makes one compile flips it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -67,10 +69,12 @@ def spec(one_chip):
 
 def _tree(spec, n, dim=3):
     m = 2 * n - 1
-    return lbvh.LBVH(spec((m, dim), f32), spec((m, dim), f32),
-                     spec((n - 1,), i32), spec((n - 1,), i32),
-                     spec((m,), i32), spec((m,), i32), spec((n,), i32),
-                     spec((n - 1,), i32))
+    lo, hi = spec((m, dim), f32), spec((m, dim), f32)
+    left, rope, rlast = spec((n - 1,), i32), spec((m,), i32), spec((m,), i32)
+    perm = spec((n,), i32)
+    table = jax.eval_shape(lbvh._pack_nodes, lo, hi, rope, rlast, left, perm)
+    return lbvh.LBVH(lo, hi, left, spec((n - 1,), i32), rope, rlast, perm,
+                     spec((n - 1,), i32), spec(table.shape, table.dtype))
 
 
 def _temp_bytes(compiled):
@@ -112,16 +116,35 @@ def _loop_ray(t, v, a, b):
     return T.traverse_knn(t, G.Points(v), P.RayNearest(G.Rays(a, b), 1), 1)
 
 
-@pytest.mark.parametrize("body", [_loop_knn, _loop_within, _loop_ray],
-                         ids=["knn", "within", "ray"])
-def test_loop_traversal_at_chip_size_fits_hbm(spec, body):
-    """The while-loop route every served kind takes on TPU. Its
-    temporaries are dominated by (2N-1, 3) node boxes relaid out with
-    the 3-wide minor dimension padded to 128 lanes (~10.7 GB here)."""
-    c = jax.jit(body).lower(
+LOOPS = pytest.mark.parametrize("body", [_loop_knn, _loop_within, _loop_ray],
+                                ids=["knn", "within", "ray"])
+
+
+def _compile_loop(spec, body):
+    return jax.jit(body).lower(
         _tree(spec, N_CHIP), spec((N_CHIP, 3), f32),
         spec((Q_BUCKET, 3), f32), spec((Q_BUCKET, 3), f32)).compile()
-    assert _temp_bytes(c) < HBM_BUDGET
+
+
+@LOOPS
+def test_loop_traversal_at_chip_size_fits_hbm(spec, body):
+    """The while-loop route every served kind takes on TPU. Its
+    temporaries (~2.15 GB here) are the (N, 3) values relaid out with the
+    3-wide minor dimension padded to 128 lanes; the node arrays, relaid
+    out the same way, added ~8.6 GB until the loop read the packed node
+    table."""
+    assert _temp_bytes(_compile_loop(spec, body)) < HBM_BUDGET
+
+
+@LOOPS
+def test_loop_traversal_reads_the_node_table_in_place(spec, body):
+    """Each step gathers a lane-dense row of the node table in the layout
+    it is stored in: no (2N-1, dim) node array is copied before the loop,
+    and what temporaries remain are the values' relayout."""
+    c = _compile_loop(spec, body)
+    copied = re.findall(r"= \w+\[(\d+)(?:,\d+)*\]\S* copy\(", c.as_text())
+    assert str(2 * N_CHIP - 1) not in copied
+    assert _temp_bytes(c) < 3e9
 
 
 @pytest.mark.parametrize("op", ["knn", "fill"])
