@@ -262,10 +262,15 @@ class ExecInfo:
     kernel_us is the device-fenced duration of the executable call (the
     ``engine.kernel`` telemetry span) — 0.0 when telemetry is disabled,
     because fencing would serialize XLA's async dispatch.
+
+    steps is set by ``exec_knn``'s loop route only: the (Q,) int32 count
+    of nodes each query visited, on the device and not awaited (DESIGN.md
+    §7, kNN loop counters).
     """
     route: str
     cache_hit: bool
     kernel_us: float = 0.0
+    steps: object = None
 
 
 class QueryEngine:
@@ -540,7 +545,8 @@ class QueryEngine:
         return out, ExecInfo(ROUTE_LOOP, hit, kus)
 
     def exec_knn(self, bvh, predicates):
-        """Cached kNN for a Nearest bucket. Returns ((dists, idxs), ExecInfo)."""
+        """Cached kNN for a Nearest bucket. Returns ((dists, idxs), ExecInfo);
+        on the loop route ``ExecInfo.steps`` holds each query's step count."""
         route = self.route_knn(bvh, predicates)
         k = predicates.k
         bq = self._rule("knn", bvh, None).block_q
@@ -577,15 +583,16 @@ class QueryEngine:
             def body(tree, values, preds):
                 self._note_trace()
                 from . import traversal as T
-                return T.traverse_knn(tree, values, preds, k)
+                return T.traverse_knn(tree, values, preds, k,
+                                      count_steps=True)
             return jax.jit(body)
 
         fn, hit = self._cached(key, make)
-        out, kus = self._launch(fn, (bvh.tree, bvh.values, predicates),
-                                route=ROUTE_LOOP, op="knn", hit=hit,
-                                node_record_lanes=lbvh.node_record_lanes(
-                                    bvh.tree.dim))
-        return out, ExecInfo(ROUTE_LOOP, hit, kus)
+        (dists, idxs, steps), kus = self._launch(
+            fn, (bvh.tree, bvh.values, predicates), route=ROUTE_LOOP,
+            op="knn", hit=hit,
+            node_record_lanes=lbvh.node_record_lanes(bvh.tree.dim))
+        return (dists, idxs), ExecInfo(ROUTE_LOOP, hit, kus, steps)
 
     def exec_ray_nearest(self, bvh, rays, k: int):
         """Cached first-k ray hits (always the general loop path).

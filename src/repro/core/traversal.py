@@ -144,16 +144,18 @@ def _insert_sorted(dists, idxs, d, i):
     return (jnp.where(take, new_d, dists), jnp.where(take, new_i, idxs))
 
 
-def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels):
+def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels,
+             count_steps: bool = False):
+    """One query's walk. With `count_steps` the carry holds one more int32,
+    the nodes this query visited (one a loop step), returned third."""
     n = tree.num_leaves
     big = jnp.asarray(jnp.inf, tree.node_lo.dtype)
 
     def cond(carry):
-        node, _, _ = carry
-        return node != -1
+        return carry[0] != -1
 
     def body(carry):
-        node, dists, idxs = carry
+        node, dists, idxs = carry[:3]
         tau = dists[k - 1]
         is_leaf = node >= n - 1
         lo, hi, rope, _, child = node_record(tree, node)
@@ -172,21 +174,26 @@ def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels):
         idxs2 = jnp.where(ok, ni, idxs)
 
         next_node = jnp.where(descend, child, rope)
-        return next_node, dists2, idxs2
+        return (next_node, dists2, idxs2) + tuple(s + 1 for s in carry[3:])
 
     dists0 = jnp.full((k,), big)
     idxs0 = jnp.full((k,), -1, jnp.int32)
-    _, dists, idxs = jax.lax.while_loop(cond, body, (jnp.int32(0), dists0, idxs0))
-    return dists, idxs
+    carry0 = (jnp.int32(0), dists0, idxs0)
+    if count_steps:
+        carry0 += (jnp.int32(0),)
+    return jax.lax.while_loop(cond, body, carry0)[1:]
 
 
-@partial(jax.jit, static_argnames=("k",))
+@partial(jax.jit, static_argnames=("k", "count_steps"))
 def traverse_knn(tree: LBVH, values, predicates, k: int, *,
-                 exclude_labels=None, leaf_labels=None):
+                 exclude_labels=None, leaf_labels=None,
+                 count_steps: bool = False):
     """Batched k-nearest traversal.
 
     Returns (dists, idxs): (N_q, k) each, padded with (inf, -1). Distances
     are FINE distances to the stored values (§2.1.2), not to leaf boxes.
+    With `count_steps`, also (N_q,) int32: the nodes each query visited
+    (its lane's loop steps; the batch's maximum is the loop's trip count).
 
     exclude_labels/leaf_labels implement Borůvka's "nearest outside my
     component" query used by EMST (§2.4).
@@ -194,6 +201,6 @@ def traverse_knn(tree: LBVH, values, predicates, k: int, *,
     ex = exclude_labels if exclude_labels is not None else jnp.full((len(predicates),), -2, jnp.int32)
 
     def one(pred, e):
-        return _knn_one(tree, values, pred, k, e, leaf_labels)
+        return _knn_one(tree, values, pred, k, e, leaf_labels, count_steps)
 
     return jax.vmap(one, in_axes=(0, 0))(predicates, ex)

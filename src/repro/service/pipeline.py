@@ -38,6 +38,8 @@ import dataclasses
 import threading
 import time
 
+import numpy as np
+
 from ..core import engine as E
 from ..core.access import default_indexable_getter
 from ..telemetry import tracer as TEL
@@ -101,6 +103,9 @@ class PipelineStatsSnapshot:
     maintenance_pending: int = 0   # gauge: queued + in-flight updates
     maintenance_errors: int = 0
     stalled_behind_maintenance: int = 0
+    knn_rows: int = 0              # real rows of kNN loop-route batches
+    knn_steps: int = 0             # ... the nodes their loops visited
+    knn_lane_steps: int = 0        # per batch: max steps x bucket slots
     #: the exception of the newest failed update (None when none failed)
     #: — kept so a caller can re-raise what ``maintenance_errors`` counts
     last_maintenance_error: BaseException | None = None
@@ -146,6 +151,13 @@ class PipelineStats:
     makes that impossible (maintenance publishes finished indexes via the
     atomic swap), so the benchmark pins it at zero.
 
+    ``knn_rows`` / ``knn_steps`` / ``knn_lane_steps`` count the batches
+    the engine's kNN loop served: real rows, the nodes their lanes
+    visited, and each batch's trip count (its largest step count; padding
+    repeats the last real row) times its bucket slots, the lane-steps the
+    vmapped loop ran. ``knn_steps / knn_lane_steps`` is the share of them
+    that did work.
+
     Since ISSUE 9 the fields are views over a per-instance telemetry
     :class:`~repro.telemetry.MetricsRegistry` (``.registry``), so they
     flow into the JSONL metrics export for free. ``queue_depth`` is a
@@ -159,7 +171,8 @@ class PipelineStats:
         "submitted", "served", "failed", "deadline_missed", "batches",
         "batch_rows", "batch_slots", "closed_full", "closed_deadline",
         "closed_drain", "swap_count", "refits", "rebuilds",
-        "maintenance_errors", "stalled_behind_maintenance")
+        "maintenance_errors", "stalled_behind_maintenance", "knn_rows",
+        "knn_steps", "knn_lane_steps")
     _GAUGE_FIELDS = ("queue_depth", "maintenance_pending")
     _FIELDS = tuple(f.name for f in
                     dataclasses.fields(PipelineStatsSnapshot))
@@ -179,6 +192,9 @@ class PipelineStats:
     rebuilds = _counter_prop("rebuilds")
     maintenance_errors = _counter_prop("maintenance_errors")
     stalled_behind_maintenance = _counter_prop("stalled_behind_maintenance")
+    knn_rows = _counter_prop("knn_rows")
+    knn_steps = _counter_prop("knn_steps")
+    knn_lane_steps = _counter_prop("knn_lane_steps")
     queue_depth = _gauge_prop("queue_depth")
     maintenance_pending = _gauge_prop("maintenance_pending")
 
@@ -559,7 +575,9 @@ class ServingPipeline:
                     deadline_missed=late, phase_us=phases, span_id=span_id)
                 ticket._complete(dataclasses.replace(resp, stats=stats))
 
-            self._account(key, group, tickets, reason, service_us, missed)
+            steps = [responses[rid].steps for rid in range(len(tickets))]
+            self._account(key, group, tickets, reason, service_us, missed,
+                          None if steps[0] is None else np.concatenate(steps))
 
     @staticmethod
     def _phase_breakdown(ticket: Ticket, t_picked: float, t_disp: float,
@@ -607,8 +625,10 @@ class ServingPipeline:
         return root
 
     def _account(self, key: tuple, group, tickets: list[Ticket],
-                 reason: str, service_us: float, missed: int):
-        """Post-scatter bookkeeping: EWMA service estimate + counters."""
+                 reason: str, service_us: float, missed: int,
+                 steps: np.ndarray | None = None):
+        """Post-scatter bookkeeping: EWMA service estimate + counters;
+        `steps` is the kNN loop's step count of each real row, if any."""
         ewma_key = (key, group.bucket)
         with self._cv:
             prev = self._est.get(ewma_key)
@@ -621,6 +641,10 @@ class ServingPipeline:
             s.batches += 1
             s.batch_rows += group.n_real
             s.batch_slots += group.bucket
+            if steps is not None:
+                s.knn_rows += len(steps)
+                s.knn_steps += int(steps.sum())
+                s.knn_lane_steps += int(steps.max()) * group.bucket
             if reason == "full":
                 s.closed_full += 1
             elif reason == "deadline":

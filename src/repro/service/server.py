@@ -73,12 +73,14 @@ class Response:
     """Per-request results. knn/ray fill (dists, idxs) (m, k) — dists are
     ray-hit parameters t for ray requests; within fills (counts, idxs)
     with idxs (m, capacity) -1-padded and `overflow` set when any query
-    matched more than `capacity`."""
+    matched more than `capacity`. `steps` (m,) is the nodes each row's
+    kNN loop visited, where the engine's loop route served it."""
     stats: RequestStats
     dists: np.ndarray | None = None
     idxs: np.ndarray | None = None
     counts: np.ndarray | None = None
     overflow: bool = False
+    steps: np.ndarray | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,6 +93,7 @@ class Launched:
     arrays: tuple
     stats: RequestStats
     capacity: int         # within: counts above it flag overflow
+    steps: object = None  # kNN loop route: (bucket,) step counts, on device
 
 
 def launch_group(engine: E.QueryEngine, config: ServiceConfig,
@@ -139,7 +142,7 @@ def launch_group(engine: E.QueryEngine, config: ServiceConfig,
                          bucket=group.bucket, index_name=entry.name,
                          index_version=entry.version,
                          cache_hit=info.cache_hit, kernel_us=info.kernel_us)
-    return Launched(group, tuple(arrays), stats, config.capacity)
+    return Launched(group, tuple(arrays), stats, config.capacity, info.steps)
 
 
 def finish_group(launched: Launched) -> dict[int, "Response"]:
@@ -150,11 +153,12 @@ def finish_group(launched: Launched) -> dict[int, "Response"]:
     runs a ``telemetry.phases`` block, as the pipeline's scheduler does."""
     group = launched.group
     TEL.phase("device_wait")
-    jax.block_until_ready(launched.arrays)
+    jax.block_until_ready((launched.arrays, launched.steps))
     TEL.phase("copy_back")
     # CSR assembly: device buffers -> host arrays (+ overflow flags)
     with TEL.span("server.assemble", kind=group.kind):
         first, idxs = (np.asarray(x) for x in launched.arrays)
+        steps = None if launched.steps is None else np.asarray(launched.steps)
     TEL.phase("deliver")
     out: dict[int, Response] = {}
     with TEL.span("server.scatter", requests=len(group.members)):
@@ -169,7 +173,9 @@ def finish_group(launched: Launched) -> dict[int, "Response"]:
             for rid, start, m in group.members:
                 sl = slice(start, start + m)
                 out[rid] = Response(launched.stats, dists=first[sl],
-                                    idxs=idxs[sl])
+                                    idxs=idxs[sl],
+                                    steps=None if steps is None
+                                    else steps[sl])
     return out
 
 

@@ -104,6 +104,12 @@ def _loop_knn(t, v, a, b):
     return T.traverse_knn(t, G.Points(v), P.nearest(G.Points(a), k=8), 8)
 
 
+def _loop_knn_steps(t, v, a, b):
+    """The engine's kNN loop route: the same walk, counting its steps."""
+    return T.traverse_knn(t, G.Points(v), P.nearest(G.Points(a), k=8), 8,
+                          count_steps=True)
+
+
 def _loop_within(t, v, a, b):
     cb, s0 = CB.collect_hits(64)
     s0 = jax.tree_util.tree_map(
@@ -116,8 +122,9 @@ def _loop_ray(t, v, a, b):
     return T.traverse_knn(t, G.Points(v), P.RayNearest(G.Rays(a, b), 1), 1)
 
 
-LOOPS = pytest.mark.parametrize("body", [_loop_knn, _loop_within, _loop_ray],
-                                ids=["knn", "within", "ray"])
+LOOPS = pytest.mark.parametrize(
+    "body", [_loop_knn, _loop_knn_steps, _loop_within, _loop_ray],
+    ids=["knn", "knn_steps", "within", "ray"])
 
 
 def _compile_loop(spec, body):
