@@ -263,14 +263,15 @@ class ExecInfo:
     ``engine.kernel`` telemetry span) — 0.0 when telemetry is disabled,
     because fencing would serialize XLA's async dispatch.
 
-    steps is set by ``exec_knn``'s loop route only: the (Q,) int32 count
-    of nodes each query visited, on the device and not awaited (DESIGN.md
-    §7, kNN loop counters).
+    knn_counts is set by ``exec_knn``'s loop route only: a (2, Q) int32
+    array on the device, not awaited, of each query's node records read
+    and 1 where its pruning bound was seeded (DESIGN.md §7, kNN loop
+    counters); one array, so the copy-back makes one transfer for both.
     """
     route: str
     cache_hit: bool
     kernel_us: float = 0.0
-    steps: object = None
+    knn_counts: object = None
 
 
 class QueryEngine:
@@ -546,7 +547,8 @@ class QueryEngine:
 
     def exec_knn(self, bvh, predicates):
         """Cached kNN for a Nearest bucket. Returns ((dists, idxs), ExecInfo);
-        on the loop route ``ExecInfo.steps`` holds each query's step count."""
+        on the loop route ``ExecInfo.knn_counts`` holds each query's step
+        count and whether its pruning bound was seeded."""
         route = self.route_knn(bvh, predicates)
         k = predicates.k
         bq = self._rule("knn", bvh, None).block_q
@@ -583,16 +585,18 @@ class QueryEngine:
             def body(tree, values, preds):
                 self._note_trace()
                 from . import traversal as T
-                return T.traverse_knn(tree, values, preds, k,
-                                      count_steps=True)
+                dists, idxs, steps, seeded = T.traverse_knn(
+                    tree, values, preds, k, count_steps=True)
+                return dists, idxs, jnp.stack([steps, seeded.astype(
+                    steps.dtype)])
             return jax.jit(body)
 
         fn, hit = self._cached(key, make)
-        (dists, idxs, steps), kus = self._launch(
+        (dists, idxs, knn_counts), kus = self._launch(
             fn, (bvh.tree, bvh.values, predicates), route=ROUTE_LOOP,
             op="knn", hit=hit,
             node_record_lanes=lbvh.node_record_lanes(bvh.tree.dim))
-        return (dists, idxs), ExecInfo(ROUTE_LOOP, hit, kus, steps)
+        return (dists, idxs), ExecInfo(ROUTE_LOOP, hit, kus, knn_counts)
 
     def exec_ray_nearest(self, bvh, rays, k: int):
         """Cached first-k ray hits (always the general loop path).

@@ -27,6 +27,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from . import geometry as G
 from . import predicates as P
 from .lbvh import LBVH, node_record
 
@@ -144,12 +145,87 @@ def _insert_sorted(dists, idxs, d, i):
     return (jnp.where(take, new_d, dists), jnp.where(take, new_i, idxs))
 
 
+def seed_window(k: int, n: int) -> int:
+    """Sorted leaves the kNN seed reads: the power of two >= 3k, at most n.
+
+    Three leaves for each neighbour sought keep the bound tight where the
+    descent ends next to a jump of the Morton order (DESIGN.md §2 item 8)."""
+    return min(1 << max(3 * k - 1, 0).bit_length(), n)
+
+
+def _descend(tree: LBVH, pred, root):
+    """One root-to-leaf path from the `root` record, into the child nearer
+    the query (ties left). Returns (sorted leaf position, records read)."""
+    n = tree.num_leaves
+
+    def mind(lo, hi):
+        return P.node_min_distance(pred, lo[None, :], hi[None, :])[0]
+
+    def body(carry):
+        _, left, reads = carry
+        lo_l, hi_l, right, _, below_l = node_record(tree, left)
+        lo_r, hi_r, _, _, below_r = node_record(tree, right)
+        go_right = mind(lo_r, hi_r) < mind(lo_l, hi_l)
+        return (jnp.where(go_right, right, left),
+                jnp.where(go_right, below_r, below_l), reads + 2)
+
+    node, _, reads = jax.lax.while_loop(
+        lambda c: c[0] < n - 1, body, (jnp.int32(0), root[4], jnp.int32(1)))
+    return node - (n - 1), reads
+
+
+def _seed_bound(tree: LBVH, values, pred, k: int, exclude_label,
+                leaf_labels):
+    """An upper bound on the query's k-th neighbour distance, from the
+    sorted leaves around the end of a one-path descent: the k-th smallest
+    admissible seed distance, widened past any rounding gap between
+    ``node_min_distance`` of a leaf's box and ``leaf_distance`` of its
+    value; inf with fewer than k admissible seeds. Returns (bound, node
+    records read)."""
+    n = tree.num_leaves
+    w = seed_window(k, n)
+    if w < k:
+        return jnp.asarray(jnp.inf, tree.node_lo.dtype), jnp.int32(0)
+    root = node_record(tree, jnp.int32(0))
+    pos, reads = _descend(tree, pred, root)
+    start = jnp.clip(pos - w // 2, 0, n - w)
+    idx = tree.leaf_perm[start + jnp.arange(w)]
+    d = P.leaf_distance(pred, value_at(values, idx))
+    big = jnp.asarray(jnp.inf, d.dtype)
+    if leaf_labels is not None:
+        d = jnp.where(leaf_labels[idx] == exclude_label, big, d)
+    kth = jnp.sort(d)[k - 1]
+    # The two formulas round apart by a share of the distance, which the
+    # factor covers, and by a few ulps of the largest coordinate involved
+    # (a sphere's box corner c + r, a segment's end rebuilt as a + (b - a)),
+    # which the second term covers: 16 * dim ulps of the query's and the
+    # tree's largest coordinate, whatever the distance.
+    qbox = G.to_boxes(pred.geom)
+    scale = (jnp.maximum(jnp.abs(root[0]), jnp.abs(root[1])).max()
+             + jnp.maximum(jnp.abs(qbox.lo), jnp.abs(qbox.hi)).max())
+    fi = jnp.finfo(d.dtype)
+    grow = 1 + max(2.0 ** -10, 16 * float(fi.eps))
+    ulps = 16 * tree.dim * float(fi.eps)
+    bound = (kth * jnp.asarray(grow, d.dtype)
+             + jnp.asarray(ulps, d.dtype) * scale.astype(d.dtype)
+             + jnp.asarray(fi.tiny, d.dtype))
+    return jnp.where(jnp.isfinite(kth), bound, big), reads
+
+
 def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels,
              count_steps: bool = False):
-    """One query's walk. With `count_steps` the carry holds one more int32,
-    the nodes this query visited (one a loop step), returned third."""
+    """One query's walk. A spatial query first seeds its pruning bound
+    from the leaves next to a one-path descent (``_seed_bound``); a ray's
+    walk starts unbounded. With `count_steps` the carry holds one more
+    int32, the nodes this query read (the descent's, then one a loop
+    step), returned third, and whether the seed bound was finite fourth."""
     n = tree.num_leaves
     big = jnp.asarray(jnp.inf, tree.node_lo.dtype)
+    if isinstance(pred, P.Nearest):
+        tau_seed, reads = _seed_bound(tree, values, pred, k, exclude_label,
+                                      leaf_labels)
+    else:   # a Morton window of leaves bounds no ray's hits
+        tau_seed, reads = big, jnp.int32(0)
 
     def cond(carry):
         return carry[0] != -1
@@ -160,7 +236,7 @@ def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels,
         is_leaf = node >= n - 1
         lo, hi, rope, _, child = node_record(tree, node)
         mind = P.node_min_distance(pred, lo[None, :], hi[None, :])[0]
-        promising = mind < tau
+        promising = mind < jnp.minimum(tau, tau_seed)
         descend = promising & ~is_leaf
 
         orig_idx = jnp.where(is_leaf, child, 0)
@@ -179,9 +255,10 @@ def _knn_one(tree: LBVH, values, pred, k: int, exclude_label, leaf_labels,
     dists0 = jnp.full((k,), big)
     idxs0 = jnp.full((k,), -1, jnp.int32)
     carry0 = (jnp.int32(0), dists0, idxs0)
-    if count_steps:
-        carry0 += (jnp.int32(0),)
-    return jax.lax.while_loop(cond, body, carry0)[1:]
+    if not count_steps:
+        return jax.lax.while_loop(cond, body, carry0)[1:]
+    out = jax.lax.while_loop(cond, body, carry0 + (reads,))
+    return out[1:] + (jnp.isfinite(tau_seed),)
 
 
 @partial(jax.jit, static_argnames=("k", "count_steps"))
@@ -192,8 +269,10 @@ def traverse_knn(tree: LBVH, values, predicates, k: int, *,
 
     Returns (dists, idxs): (N_q, k) each, padded with (inf, -1). Distances
     are FINE distances to the stored values (§2.1.2), not to leaf boxes.
-    With `count_steps`, also (N_q,) int32: the nodes each query visited
-    (its lane's loop steps; the batch's maximum is the loop's trip count).
+    With `count_steps`, also (N_q,) int32, the nodes each query read (its
+    seed descent's records plus its lane's loop steps), and (N_q,) bool,
+    whether its pruning bound was seeded (a finite bound from k admissible
+    leaves near it; DESIGN.md §2 item 8).
 
     exclude_labels/leaf_labels implement Borůvka's "nearest outside my
     component" query used by EMST (§2.4).

@@ -106,6 +106,7 @@ class PipelineStatsSnapshot:
     knn_rows: int = 0              # real rows of kNN loop-route batches
     knn_steps: int = 0             # ... the nodes their loops visited
     knn_lane_steps: int = 0        # per batch: max steps x bucket slots
+    knn_seeded_rows: int = 0       # ... rows whose pruning bound was seeded
     #: the exception of the newest failed update (None when none failed)
     #: — kept so a caller can re-raise what ``maintenance_errors`` counts
     last_maintenance_error: BaseException | None = None
@@ -156,7 +157,8 @@ class PipelineStats:
     visited, and each batch's trip count (its largest step count; padding
     repeats the last real row) times its bucket slots, the lane-steps the
     vmapped loop ran. ``knn_steps / knn_lane_steps`` is the share of them
-    that did work.
+    that did work. ``knn_seeded_rows`` counts the real rows whose walk
+    started from a finite seeded bound (DESIGN.md §2 item 8).
 
     Since ISSUE 9 the fields are views over a per-instance telemetry
     :class:`~repro.telemetry.MetricsRegistry` (``.registry``), so they
@@ -172,7 +174,7 @@ class PipelineStats:
         "batch_rows", "batch_slots", "closed_full", "closed_deadline",
         "closed_drain", "swap_count", "refits", "rebuilds",
         "maintenance_errors", "stalled_behind_maintenance", "knn_rows",
-        "knn_steps", "knn_lane_steps")
+        "knn_steps", "knn_lane_steps", "knn_seeded_rows")
     _GAUGE_FIELDS = ("queue_depth", "maintenance_pending")
     _FIELDS = tuple(f.name for f in
                     dataclasses.fields(PipelineStatsSnapshot))
@@ -195,6 +197,7 @@ class PipelineStats:
     knn_rows = _counter_prop("knn_rows")
     knn_steps = _counter_prop("knn_steps")
     knn_lane_steps = _counter_prop("knn_lane_steps")
+    knn_seeded_rows = _counter_prop("knn_seeded_rows")
     queue_depth = _gauge_prop("queue_depth")
     maintenance_pending = _gauge_prop("maintenance_pending")
 
@@ -575,9 +578,12 @@ class ServingPipeline:
                     deadline_missed=late, phase_us=phases, span_id=span_id)
                 ticket._complete(dataclasses.replace(resp, stats=stats))
 
-            steps = [responses[rid].steps for rid in range(len(tickets))]
-            self._account(key, group, tickets, reason, service_us, missed,
-                          None if steps[0] is None else np.concatenate(steps))
+            resps = [responses[rid] for rid in range(len(tickets))]
+            loop = resps[0].steps is not None
+            self._account(
+                key, group, tickets, reason, service_us, missed,
+                np.concatenate([r.steps for r in resps]) if loop else None,
+                np.concatenate([r.seeded for r in resps]) if loop else None)
 
     @staticmethod
     def _phase_breakdown(ticket: Ticket, t_picked: float, t_disp: float,
@@ -626,9 +632,11 @@ class ServingPipeline:
 
     def _account(self, key: tuple, group, tickets: list[Ticket],
                  reason: str, service_us: float, missed: int,
-                 steps: np.ndarray | None = None):
+                 steps: np.ndarray | None = None,
+                 seeded: np.ndarray | None = None):
         """Post-scatter bookkeeping: EWMA service estimate + counters;
-        `steps` is the kNN loop's step count of each real row, if any."""
+        `steps` is the kNN loop's step count of each real row, if any, and
+        `seeded` whether the row's pruning bound was seeded."""
         ewma_key = (key, group.bucket)
         with self._cv:
             prev = self._est.get(ewma_key)
@@ -645,6 +653,7 @@ class ServingPipeline:
                 s.knn_rows += len(steps)
                 s.knn_steps += int(steps.sum())
                 s.knn_lane_steps += int(steps.max()) * group.bucket
+                s.knn_seeded_rows += int(seeded.sum())
             if reason == "full":
                 s.closed_full += 1
             elif reason == "deadline":

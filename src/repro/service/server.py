@@ -74,13 +74,15 @@ class Response:
     ray-hit parameters t for ray requests; within fills (counts, idxs)
     with idxs (m, capacity) -1-padded and `overflow` set when any query
     matched more than `capacity`. `steps` (m,) is the nodes each row's
-    kNN loop visited, where the engine's loop route served it."""
+    kNN loop read, and `seeded` (m,) whether its pruning bound was seeded,
+    where the engine's loop route served it."""
     stats: RequestStats
     dists: np.ndarray | None = None
     idxs: np.ndarray | None = None
     counts: np.ndarray | None = None
     overflow: bool = False
     steps: np.ndarray | None = None
+    seeded: np.ndarray | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,7 +95,7 @@ class Launched:
     arrays: tuple
     stats: RequestStats
     capacity: int         # within: counts above it flag overflow
-    steps: object = None  # kNN loop route: (bucket,) step counts, on device
+    knn_counts: object = None  # kNN loop route: (2, bucket) steps, seeded
 
 
 def launch_group(engine: E.QueryEngine, config: ServiceConfig,
@@ -142,7 +144,8 @@ def launch_group(engine: E.QueryEngine, config: ServiceConfig,
                          bucket=group.bucket, index_name=entry.name,
                          index_version=entry.version,
                          cache_hit=info.cache_hit, kernel_us=info.kernel_us)
-    return Launched(group, tuple(arrays), stats, config.capacity, info.steps)
+    return Launched(group, tuple(arrays), stats, config.capacity,
+                    info.knn_counts)
 
 
 def finish_group(launched: Launched) -> dict[int, "Response"]:
@@ -153,12 +156,13 @@ def finish_group(launched: Launched) -> dict[int, "Response"]:
     runs a ``telemetry.phases`` block, as the pipeline's scheduler does."""
     group = launched.group
     TEL.phase("device_wait")
-    jax.block_until_ready((launched.arrays, launched.steps))
+    jax.block_until_ready((launched.arrays, launched.knn_counts))
     TEL.phase("copy_back")
     # CSR assembly: device buffers -> host arrays (+ overflow flags)
     with TEL.span("server.assemble", kind=group.kind):
         first, idxs = (np.asarray(x) for x in launched.arrays)
-        steps = None if launched.steps is None else np.asarray(launched.steps)
+        loop = (None if launched.knn_counts is None
+                else np.asarray(launched.knn_counts))
     TEL.phase("deliver")
     out: dict[int, Response] = {}
     with TEL.span("server.scatter", requests=len(group.members)):
@@ -174,8 +178,10 @@ def finish_group(launched: Launched) -> dict[int, "Response"]:
                 sl = slice(start, start + m)
                 out[rid] = Response(launched.stats, dists=first[sl],
                                     idxs=idxs[sl],
-                                    steps=None if steps is None
-                                    else steps[sl])
+                                    steps=None if loop is None
+                                    else loop[0, sl],
+                                    seeded=None if loop is None
+                                    else loop[1, sl].astype(bool))
     return out
 
 

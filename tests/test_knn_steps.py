@@ -1,11 +1,14 @@
 """The kNN loop's step count and the pipeline's counters built on it.
 
-The engine's kNN loop route counts, per query, the nodes its lane of the
-vmapped ``while_loop`` visited (``traverse_knn(count_steps=True)``). The
-count comes back with the answers, and ``PipelineStats`` sums it into
-``knn_rows`` / ``knn_steps`` / ``knn_lane_steps``. These tests pin that the
+The engine's kNN loop route counts, per query, the nodes it read: its
+seed descent's records, then one a step of its lane of the vmapped
+``while_loop`` (``traverse_knn(count_steps=True)``), and flags whether
+its pruning bound was seeded. Both come back with the answers, and
+``PipelineStats`` sums them into ``knn_rows`` / ``knn_steps`` /
+``knn_lane_steps`` / ``knn_seeded_rows``. These tests pin that the
 answers are exact and unchanged by the count, that the count is the
-rope-order walk's own, and that every other kNN path keeps its answers.
+seeded rope-order walk's own, and that every other kNN path keeps its
+answers.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -79,29 +82,60 @@ def test_answers_bit_identical_to_the_loop_without_the_count(served):
     assert np.array_equal(np.asarray(d).view(np.int32),
                           served["full"].dists.view(np.int32))
     assert np.array_equal(np.asarray(i), served["full"].idxs)
-    d2, i2, steps = T.traverse_knn(served["tree"], served["values"], preds,
-                                   K, count_steps=True)
+    d2, i2, steps, _ = T.traverse_knn(served["tree"], served["values"],
+                                      preds, K, count_steps=True)
     assert np.array_equal(np.asarray(d2).view(np.int32),
                           np.asarray(d).view(np.int32))
     assert np.array_equal(np.asarray(i2), np.asarray(i))
     assert np.array_equal(np.asarray(steps), served["full"].steps)
 
 
+def _seed(tree, pts, q):
+    """The seed in NumPy: one path into the nearer child (ties left), then
+    the k-th smallest distance of the sorted leaves around its end, widened
+    as the loop widens it. Returns (node records read, bound)."""
+    lo, hi = np.asarray(tree.node_lo), np.asarray(tree.node_hi)
+    rope, left = np.asarray(tree.rope), np.asarray(tree.left_child)
+    perm = np.asarray(tree.leaf_perm)
+    n = perm.shape[0]
+
+    def mind(node):
+        gap = np.maximum(np.maximum(lo[node] - q, q - hi[node]),
+                         np.float32(0))
+        return np.sqrt(np.sum(gap * gap))
+
+    node, reads = 0, 1
+    while node < n - 1:
+        near, far = left[node], rope[left[node]]
+        node = far if mind(far) < mind(near) else near
+        reads += 2
+    w = T.seed_window(K, n)
+    start = min(max(node - (n - 1) - w // 2, 0), n - w)
+    d = np.sort(np.sqrt(np.sum((q - pts[perm[start:start + w]]) ** 2, -1)))
+    fi = np.finfo(np.float32)
+    scale = (np.maximum(np.abs(lo[0]), np.abs(hi[0])).max()
+             + np.abs(q).max())
+    return reads, (d[K - 1] * np.float32(1 + 2 ** -10)
+                   + np.float32(16 * q.shape[-1] * fi.eps) * scale + fi.tiny)
+
+
 def _rewalk(tree, pts, q):
-    """The rope-order walk in NumPy: one step a node, pruned by the k-th
-    candidate distance `tau` as the loop prunes. Returns (steps, dists)."""
+    """The kNN walk in NumPy: the seed's reads, then one step a node in
+    rope order, pruned by the smaller of the seed and the k-th candidate
+    distance `tau` as the loop prunes. Returns (steps, dists)."""
     lo, hi = np.asarray(tree.node_lo), np.asarray(tree.node_hi)
     rope, left = np.asarray(tree.rope), np.asarray(tree.left_child)
     perm = np.asarray(tree.leaf_perm)
     n = perm.shape[0]
     dists = np.full(K, np.inf, np.float32)
-    node, steps = 0, 0
+    steps, seed = _seed(tree, pts, q)
+    node = 0
     while node != -1:
         steps += 1
         tau = dists[K - 1]
         gap = np.maximum(np.maximum(lo[node] - q, q - hi[node]),
                          np.float32(0))
-        promising = np.sqrt(np.sum(gap * gap)) < tau
+        promising = np.sqrt(np.sum(gap * gap)) < min(tau, seed)
         if node >= n - 1:
             d = np.sqrt(np.sum((q - pts[perm[node - (n - 1)]]) ** 2))
             if promising and d < tau:
@@ -140,12 +174,26 @@ def test_pipeline_counters_advance_by_rows_steps_and_lane_steps(served):
     assert 0 < s2.knn_steps <= s2.knn_lane_steps
 
 
+def test_seeded_rows_sum_like_rows(served):
+    """Every row of the uniform cloud starts from a seeded bound, so
+    ``knn_seeded_rows`` advances with ``knn_rows``: the real rows' flags,
+    not the padding's."""
+    s0, s1, s2 = served["stats"]
+    full, short = served["full"].seeded, served["short"].seeded
+    assert full.dtype == np.bool_ and full.shape == (Q,)
+    assert short.shape == (SHORT,) and full.all() and short.all()
+    assert s1.knn_seeded_rows - s0.knn_seeded_rows == int(full.sum()) == Q
+    assert s2.knn_seeded_rows - s1.knn_seeded_rows == int(short.sum())
+    assert s2.knn_seeded_rows == s2.knn_rows
+
+
 def test_counters_appear_in_the_metrics_export(served, tmp_path):
     path = str(tmp_path / "pipeline.jsonl")
     assert write_metrics_jsonl(path, served["registry"]) > 0
     back = read_metrics_jsonl(path)
     last = served["stats"][-1]
-    for name in ("knn_rows", "knn_steps", "knn_lane_steps"):
+    for name in ("knn_rows", "knn_steps", "knn_lane_steps",
+                 "knn_seeded_rows"):
         rec = back[f"pipeline.{name}"]
         assert rec["type"] == "counter"
         assert rec["value"] == getattr(last, name) > 0
@@ -164,7 +212,8 @@ def test_brute_force_route_counts_nothing():
     finally:
         pipe.close()
     assert resp.stats.route == "bruteforce" and resp.steps is None
-    assert (st.knn_rows, st.knn_steps, st.knn_lane_steps) == (0, 0, 0)
+    assert (st.knn_rows, st.knn_steps, st.knn_lane_steps,
+            st.knn_seeded_rows) == (0, 0, 0, 0)
 
 
 @pytest.mark.parametrize("path", ["sharded_local_stage", "bvh_query"])
