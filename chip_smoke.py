@@ -478,13 +478,12 @@ def main(argv=None) -> int:
     from repro.kernels import lbvh_build
 
     cache = configure_compile_cache()
-    table = E.default_engine().table()
     log(f"backend: {jax.default_backend()}  device_kind: "
         f"{devs[0].device_kind}  device_count: {len(devs)}  jax "
         f"{jax.__version__}")
     log(f"compile cache: {cache}")
-    log(f"route table: {table.source}"
-        + (f" ({table.reason})" if table.reason else ""))
+    log(f"engine rule: brute force while N*Q <= {E.BF_MAX_WORK}, "
+        "else the loop")
     log(f"build engine: {lbvh._resolve_build_engine('auto')}; karras "
         f"ranges: jit twin ({lbvh_build.PALLAS_TPU_REASON})")
 

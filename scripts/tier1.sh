@@ -29,8 +29,8 @@ python -m repro.analysis --strict
 # the schemas, render the report tables.
 python -m repro.telemetry.report --selftest
 
-# runtime kernel contracts: interpret-mode re-execution of all four
-# Pallas kernel modules with REPRO_SANITIZE assertions armed, vs
+# runtime kernel contracts: interpret-mode re-execution of the Karras
+# and brute-force kNN kernels with REPRO_SANITIZE assertions armed, vs
 # oracles (seconds-scale, N=2000, fixed seed).
 python -m repro.analysis --sanitize-smoke
 # DeprecationWarnings are errors: the legacy API-v1 spellings (space-first
@@ -39,22 +39,3 @@ python -m repro.analysis --sanitize-smoke
 python -m pytest -q -p no:cacheprovider \
     -W error::DeprecationWarning "$@"
 
-# async-pipeline smoke (seconds-scale, fixed seed, tiny N): exercises the
-# deadline scheduler + background maintenance swap on every tier-1 run.
-# Prints metrics only — run.py owns persisting them to BENCH_service.json.
-python -m benchmarks.bench_pipeline --smoke
-
-# sharded-serving smoke (DESIGN.md §11): multi-device subprocesses under a
-# forced host device count — builds, serves, and distributed-refits a
-# ShardedIndexStore on 1- and 2-shard meshes, asserts all four collective
-# phase spans fired, and oracle-checks the served results.
-python -m benchmarks.bench_sharded --smoke
-
-# construction smoke (ISSUE 7): fused Pallas build vs reference build at a
-# fixed seed — raises if the trees are not bit-identical node-for-node.
-python -m benchmarks.bench_construction --smoke
-
-# route-table schema validation: a corrupt/stale persisted
-# ROUTE_TABLE.json fails loudly here instead of silently mis-routing
-# (absent table or foreign-hardware fingerprint is fine).
-python -m benchmarks.autotune --validate

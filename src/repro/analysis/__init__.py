@@ -11,7 +11,7 @@ Three pass families over the serving/engine/kernel code:
   * kernel sanitizer (:mod:`.pallas_trace`, ``--strict`` only) —
     PLK001/PLK002: static VMEM footprint and race-free output index maps,
     measured by spying on real ``pl.pallas_call`` launches at the largest
-    shapes the route table admits.
+    shapes each kernel declares.
 
 The default run is stdlib-only (pure ``ast`` — it must stay importable
 and fast with no jax present); ``strict=True`` adds the launch-capture

@@ -35,7 +35,7 @@ RULES = {
     "TRC004": "jitted executable closes over a value missing from its "
               "cache key (silent recompile / stale-executable hazard)",
     "PLK001": "pallas kernel VMEM footprint exceeds its declared budget at "
-              "the largest shapes the route table admits",
+              "the largest shapes the kernel declares",
     "PLK002": "two parallel grid cells write overlapping output blocks "
               "(index_map is not race-free)",
     "PLK003": "unclamped dynamic indexing inside a pallas kernel (gather "

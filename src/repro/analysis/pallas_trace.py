@@ -3,10 +3,10 @@
 Each kernel module publishes ``REPROLINT_SPECS`` — a zero-arg callable
 returning launch specs::
 
-    {"name": "spatial@route-limits",    # what envelope this pins
+    {"name": "bruteforce-knn@max-k",    # what envelope this pins
      "call": <zero-arg thunk>,          # invokes the kernel wrapper at the
-                                        # LARGEST shapes the route table
-                                        # admits (jax.eval_shape-safe)
+                                        # LARGEST shapes it is declared
+                                        # for (jax.eval_shape-safe)
      "budget": 16 * 2**20}              # optional VMEM budget override
 
 The analyzer monkeypatches ``pl.pallas_call`` with a spy and runs every
@@ -16,10 +16,10 @@ checks each recorded launch:
 
 * **PLK001** — static VMEM footprint: Σ input-block bytes + output-block
   bytes + scratch bytes must fit the budget (~16 MB of VMEM on TPU v5e).
-  The route table's admission limits (``pallas_max_nodes`` /
-  ``pallas_max_capacity``) are exactly the knobs that keep this true, so
-  the specs derive their shapes from ``RouteTable.default()`` — tighten a
-  kernel or loosen a rule and the gate recomputes the consequence.
+  Each kernel's declared limits (``PALLAS_MAX_LEAVES``,
+  ``REPROLINT_MAX_K``) are the knobs that keep this true, so its specs
+  launch at them — tighten a kernel or raise a limit and the gate
+  recomputes the consequence.
 * **PLK002** — race-free outputs: no two grid cells that can run
   concurrently (i.e. differ along a ``"parallel"`` grid axis) may map to
   the same output block. Cells differing only along ``"arbitrary"``
@@ -43,10 +43,8 @@ from .findings import Finding
 __all__ = ["run", "capture", "check_launch", "KERNEL_MODULES",
            "DEFAULT_BUDGET"]
 
-#: the four kernel modules the sanitizer gates (ISSUE 8 scope)
+#: the kernel modules the sanitizer gates
 KERNEL_MODULES = (
-    "repro.kernels.bvh_traverse",
-    "repro.kernels.bvh_callback",
     "repro.kernels.lbvh_build",
     "repro.kernels.bruteforce_knn",
 )
@@ -162,8 +160,7 @@ def check_launch(launch: Launch, budget: int, label: str) -> list:
             "PLK001", launch.path, launch.line,
             f"kernel launch [{label}] stages {total / 2**20:.1f} MB of "
             f"blocks into VMEM (budget {budget / 2**20:.1f} MB)",
-            hint="shrink the admitted envelope (route-table "
-                 "pallas_max_nodes / pallas_max_capacity) or tile the "
+            hint="lower the kernel's declared limit or tile the "
                  "offending operand instead of staging it whole"))
 
     # --- PLK002: race-free output index maps ---------------------------

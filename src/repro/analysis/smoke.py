@@ -8,10 +8,6 @@ calls see concrete outputs, so all the :mod:`repro.kernels.sanitize`
 assertions are live, and interpret mode makes OOB block reads fault
 instead of wrapping:
 
-  * ``bvh_traverse_spatial``   — counts vs an all-pairs numpy oracle,
-  * ``bvh_traverse_knn``       — distances vs the numpy oracle,
-  * ``bvh_traverse_callback``  — final states vs the while-loop
-    ``traversal.traverse`` reference (bit-identical),
   * ``karras_ranges``          — the sanitize path itself runs BOTH the
     pallas kernel and the fused jit twin and asserts they agree,
   * ``ops.bruteforce_knn``     — vs the numpy oracle.
@@ -37,16 +33,8 @@ def run(n: int = 2000, q: int = 256, seed: int = 0, echo=print) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from ..core import callbacks as CB
-    from ..core import geometry as G
     from ..core import morton as M
-    from ..core import predicates as P
-    from ..core import traversal as T
-    from ..core.index import _bcast_state
-    from ..core.lbvh import build
     from ..kernels import ops, sanitize
-    from ..kernels.bvh_callback import bvh_traverse_callback
-    from ..kernels.bvh_traverse import bvh_traverse_knn, bvh_traverse_spatial
     from ..kernels.lbvh_build import karras_ranges
 
     _expect(sanitize.enabled(), "REPRO_SANITIZE did not arm")
@@ -58,42 +46,6 @@ def run(n: int = 2000, q: int = 256, seed: int = 0, echo=print) -> int:
     dist = np.sqrt(((qp_np[:, None, :].astype(np.float64)
                      - pts_np[None, :, :]) ** 2).sum(-1))       # (q, n)
 
-    tree = build(G.Boxes(pts, pts))
-    tree_args = (tree.node_lo, tree.node_hi, tree.rope, tree.left_child,
-                 tree.range_last, tree.leaf_perm)
-
-    # --- spatial fill ------------------------------------------------------
-    r = 0.1
-    rad = jnp.full((q,), r, jnp.float32)
-    cnt, buf = bvh_traverse_spatial(*tree_args, qp, qp, rad, capacity=64,
-                                    fine_sqrt=True, interpret=True)
-    want_cnt = (dist <= r).sum(-1)
-    _expect(np.array_equal(np.asarray(cnt), want_cnt),
-            "bvh_traverse_spatial counts differ from the all-pairs oracle")
-    echo(f"  spatial ok   (n={n}, q={q}, mean count "
-         f"{float(want_cnt.mean()):.1f})")
-
-    # --- kNN ---------------------------------------------------------------
-    k = 8
-    d_k, i_k = bvh_traverse_knn(tree.node_lo, tree.node_hi, tree.rope,
-                                tree.left_child, tree.leaf_perm, qp, k=k,
-                                interpret=True)
-    want_d = np.sort(dist, axis=-1)[:, :k]
-    _expect(np.allclose(np.asarray(d_k), want_d, rtol=1e-4, atol=1e-5),
-            "bvh_traverse_knn distances differ from the oracle")
-    echo(f"  knn ok       (k={k})")
-
-    # --- callback ----------------------------------------------------------
-    cb, s0 = CB.counting()
-    preds = P.intersects(G.Spheres(qp, rad))
-    s0b = _bcast_state(s0, q)
-    got = bvh_traverse_callback(*tree_args, G.Points(pts), preds, cb, s0b,
-                                interpret=True)
-    want = T.traverse(tree, G.Points(pts), preds, cb, s0b)
-    _expect(np.array_equal(np.asarray(got), np.asarray(want)),
-            "bvh_traverse_callback states differ from traversal.traverse")
-    echo("  callback ok  (counting vs while-loop reference)")
-
     # --- karras ranges: the sanitize path runs pallas AND fused twins ------
     codes = M.morton64(pts)
     codes_s, _ = M.sort_by_morton(codes, jnp.arange(n, dtype=jnp.int32))
@@ -103,6 +55,8 @@ def run(n: int = 2000, q: int = 256, seed: int = 0, echo=print) -> int:
     echo("  karras ok    (pallas twin == fused twin, contracts hold)")
 
     # --- bruteforce kNN ----------------------------------------------------
+    k = 8
+    want_d = np.sort(dist, axis=-1)[:, :k]
     d_b, i_b = ops.bruteforce_knn(qp, pts, k)
     _expect(np.allclose(np.asarray(d_b), want_d, rtol=1e-4, atol=1e-5),
             "ops.bruteforce_knn distances differ from the oracle")

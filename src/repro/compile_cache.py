@@ -1,5 +1,5 @@
 """Where JAX keeps its persistent compilation cache for this repo's entry
-scripts (``chip_smoke.py``, ``benchmarks/run.py``).
+scripts (``chip_smoke.py``, ``bench/run.py``).
 
 Called by those scripts only, never when the library is imported: a
 library must not decide where its users' caches live.

@@ -4,7 +4,7 @@ primary contribution). See DESIGN.md for the GPU->TPU adaptation map.
 The front door is the unified Index protocol (DESIGN.md §6): BVH,
 BruteForce, and DistributedTree all construct from (values,
 indexable_getter, policy) and answer one polymorphic ``query()``."""
-from . import access, callbacks, engine, geometry, morton, predicates, route_table, traversal
+from . import access, callbacks, engine, geometry, morton, predicates, traversal
 from .brute_force import BruteForce
 from .bvh import BVH
 from .dbscan import dbscan
@@ -16,16 +16,14 @@ from .interpolation import mls_interpolate
 from .lbvh import LBVH, build, refit, sah_cost
 from .predicates import intersects, nearest
 from .raytracing import cast_intersect, cast_nearest, cast_ordered
-from .route_table import RouteRule, RouteTable, hardware_fingerprint
 
 __all__ = [
     "Index", "ExecutionPolicy", "QueryResult",
     "BVH", "BruteForce", "DistributedTree", "LBVH", "build", "refit",
     "sah_cost",
     "QueryEngine", "EngineConfig", "default_engine", "set_default_engine",
-    "RouteRule", "RouteTable", "hardware_fingerprint",
     "intersects", "nearest", "dbscan", "emst", "mls_interpolate",
     "cast_nearest", "cast_intersect", "cast_ordered",
     "access", "callbacks", "engine", "geometry", "morton", "predicates",
-    "route_table", "traversal",
+    "traversal",
 ]

@@ -9,7 +9,7 @@ distance matrix is a matmul
 
 that runs on the MXU at matmul throughput, while the BVH traversal runs on
 the VPU. The crossover point between BruteForce and BVH therefore sits at
-much larger N on TPU; `benchmarks/bench_bruteforce.py` measures it.
+much larger N on TPU; the engine's ``BF_MAX_WORK`` (DESIGN.md §3) places it.
 
 The pure-JAX implementation below tiles queries into blocks of `block_q` so
 the (Q, N) distance matrix never materializes. The Pallas kernel variant

@@ -100,11 +100,6 @@ class BVH(Index):
         boxes = indexable_getter(values)
         self._n = len(boxes)
         self._boxes = boxes
-        # the fused kernel's leaf test is the box test; it is exact only for
-        # values whose fine test equals their bounding-box test
-        self.pallas_values_ok = (
-            indexable_getter is default_indexable_getter
-            and isinstance(values, (G.Points, G.Boxes)))
         self._bf = None
 
     @property
@@ -135,31 +130,21 @@ class BVH(Index):
 
     # --- backend SPI ------------------------------------------------------
     def _query_callback_impl(self, predicates, callback, state0, pol):
-        """Callback flavor, engine-dispatched: the fused kernel runs the
-        callback inside the traversal epilogue (results compressed in
-        VMEM, CSR never materialized); the while loop is the general
-        fallback. Per-query final states are bit-identical either way."""
+        """Callback flavor: the while loop runs the callback on every match
+        in traversal order (CSR never materialized)."""
         if self.tree is None:
             return _degenerate_callback(self.values, self._boxes, self._n,
                                         predicates, callback, state0)
-        engine = pol.resolve_engine()
-        if engine.route_callback(self, predicates, state0,
-                                 policy=pol) == E.ROUTE_PALLAS:
-            return engine.pallas_callback(self, predicates, callback, state0,
-                                          policy=pol)
         return T.traverse(self.tree, self.values, predicates, callback, state0)
 
     def _count_impl(self, predicates, pol):
         """Per-query match counts, dispatched by the engine (DESIGN.md §3):
-        MXU all-pairs, fused Pallas traversal, or the vmapped while loop.
-        All three produce identical int32 counts."""
+        MXU all-pairs or the vmapped while loop. Both produce identical
+        int32 counts."""
         engine = pol.resolve_engine()
-        if self.tree is not None:
-            route = engine.route_spatial(self, predicates)
-            if route == E.ROUTE_BRUTEFORCE:
-                return self._brute()._count_impl(predicates, pol)
-            if route == E.ROUTE_PALLAS:
-                return engine.pallas_count(self, predicates)
+        if (self.tree is not None and engine.route_spatial(self, predicates)
+                == E.ROUTE_BRUTEFORCE):
+            return self._brute()._count_impl(predicates, pol)
         cb, s0 = CB.counting()
         return self._query_callback_impl(predicates, cb,
                                          _bcast_state(s0, len(predicates)), pol)
@@ -169,12 +154,10 @@ class BVH(Index):
         `capacity` matched indices per query (engine-dispatched; the match
         SET per query is path-independent, the buffer order is not)."""
         engine = pol.resolve_engine()
-        if self.tree is not None:
-            route = engine.route_spatial(self, predicates, capacity)
-            if route == E.ROUTE_BRUTEFORCE:
-                return self._brute()._fill_impl(predicates, capacity, pol)
-            if route == E.ROUTE_PALLAS:
-                return engine.pallas_fill(self, predicates, capacity)
+        if (self.tree is not None
+                and engine.route_spatial(self, predicates, capacity)
+                == E.ROUTE_BRUTEFORCE):
+            return self._brute()._fill_impl(predicates, capacity, pol)
         cb, s0 = CB.collect_hits(capacity)
         count, idxs, _ = self._query_callback_impl(
             predicates, cb, _bcast_state(s0, len(predicates)), pol)
@@ -206,12 +189,9 @@ class BVH(Index):
         if self.tree is None:
             return _degenerate_knn(self.values, self._boxes, self._n,
                                    predicates, k)
-        engine = pol.resolve_engine()
-        route = engine.route_knn(self, predicates)
-        if route == E.ROUTE_BRUTEFORCE:
+        if pol.resolve_engine().route_knn(self, predicates) == \
+                E.ROUTE_BRUTEFORCE:
             return self._brute()._knn_impl(predicates, pol)
-        if route == E.ROUTE_PALLAS:
-            return engine.pallas_knn(self, predicates)
         return T.traverse_knn(self.tree, self.values, predicates, k)
 
 

@@ -31,8 +31,7 @@ Structure (mirrors the paper):
     default — reduce data-side with ``callback=`` instead of shipping
     values. Attach-data scenarios that DO need the matched values opt in
     with ``policy.override(ship_values=True)``; the collective then moves
-    exactly the matched rows. ``benchmarks/bench_distributed.py`` measures
-    the collective-byte saving straight from the lowered HLO.
+    exactly the matched rows.
 
 All paths are jit/shard_map-closed: shapes are static, results land
 sharded over the same axis as the originating predicates (whose batch
@@ -403,9 +402,7 @@ def ship_values_baseline(tree: DistributedTree, queries, radius,
     _warn_deprecated(
         "ship_values_baseline", "ship_values_baseline is deprecated; use "
         "query(predicates, policy=policy.override(ship_values=True)) — "
-        "QueryResult.values then carries the matched values. The helper "
-        "remains only as the fixed-capacity HLO baseline for "
-        "benchmarks/bench_distributed.py")
+        "QueryResult.values then carries the matched values")
     if not isinstance(tree.values, G.Points):
         raise TypeError("ship_values_baseline requires Points values")
     axis, R, n_local = tree.axis, tree.R, tree.n_local

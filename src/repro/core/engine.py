@@ -1,183 +1,68 @@
-"""QueryEngine: per-batch dispatch between the three query execution paths
+"""QueryEngine: per-batch dispatch between the two query execution paths
 (DESIGN.md §3).
 
 ArborX 2.0's headline is that the *same* query API is served by different
 index structures whose crossover depends on hardware (brute force wins for
-small N / fat queries; the BVH wins asymptotically). On TPU there are three
-distinct engines for one batched query:
+small N / fat queries; the BVH wins asymptotically). Here one batched query
+runs on one of two routes:
 
   * ``bruteforce`` — the MXU path: all-pairs leaf tests / distance matrix
     (``BruteForce``). Exact by construction; fastest while N·Q is small
     because a (Q, N) panel is one matmul-shaped pass.
-  * ``pallas``     — the fused stackless-traversal kernel
-    (``kernels.bvh_traverse``): whole tree staged through VMEM, a block of
-    queries per grid cell, one int32 cursor per lane.
   * ``loop``       — the vmapped ``lax.while_loop`` traversal
     (``core.traversal``): fully general (any predicate kind, any value
-    geometry, arbitrary callbacks); the fallback whenever a query is not
-    expressible in the kernel's unified box/r² form.
+    geometry, arbitrary callbacks).
 
-Routing is static (Python-level: N, Q, predicate type, value geometry), so
-it never traces into jit. The crossover thresholds live in a declarative
-:class:`~repro.core.route_table.RouteTable` (autotuned per hardware by
-``benchmarks/autotune.py``); lookup order is explicit policy table >
-engine-config table > persisted ``ROUTE_TABLE.json`` > built-in defaults,
-with ``REPRO_ENGINE_FORCE`` pinning a route outright for A/B runs.
+The rule is one comparison on every backend: a batch whose predicate kind
+brute force can answer takes it while N·Q <= :data:`BF_MAX_WORK`, and the
+loop otherwise. Routing is static (Python-level: N, Q, predicate type), so
+it never traces into jit.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-import os
 import threading
 
 import jax
 import jax.numpy as jnp
 
-from ..kernels.bvh_callback import bvh_traverse_callback
-from ..kernels.bvh_traverse import bvh_traverse_knn, bvh_traverse_spatial
 from ..telemetry import tracer as TEL
-from . import geometry as G
 from . import lbvh
 from . import predicates as P
-from . import route_table as RT
 
 __all__ = ["EngineConfig", "EngineStats", "EngineStatsSnapshot", "ExecInfo",
            "QueryEngine", "default_engine", "set_default_engine",
-           "ROUTE_BRUTEFORCE", "ROUTE_PALLAS", "ROUTE_LOOP"]
+           "ROUTE_BRUTEFORCE", "ROUTE_LOOP", "BF_MAX_WORK"]
 
 ROUTE_BRUTEFORCE = "bruteforce"
-ROUTE_PALLAS = "pallas"
 ROUTE_LOOP = "loop"
 
-#: old EngineConfig crossover field -> RouteRule field (deprecation shims)
-_LEGACY_CROSSOVERS = {
-    "brute_force_max_work": "bf_max_work",
-    "pallas_min_queries": "pallas_min_queries",
-    "pallas_min_leaves": "pallas_min_leaves",
-    "pallas_max_nodes": "pallas_max_nodes",
-    "pallas_max_capacity": "pallas_max_capacity",
-}
+#: largest N·Q a batch answers by brute force: a 1024-query batch up to
+#: 4096 points. It is the crossover the TPU runs at, and every backend
+#: follows it, so CPU tests take the routes the chip takes. No benchmark
+#: cell measures the crossover yet (both sit far past it, on the loop)
+BF_MAX_WORK = 1 << 22
 
 
 @dataclasses.dataclass
 class EngineConfig:
-    """Engine-level knobs. The crossover thresholds themselves live in a
-    :class:`~repro.core.route_table.RouteTable` (per-op rules, autotuned
-    per hardware); the old per-field constants are warn-once deprecation
-    shims that synthesize a single-row table.
+    """Engine-level knobs.
 
-    route_table: a RouteTable (or a path to a persisted one) used for
-        every routing decision through this engine; None defers to the
-        ambient persisted table (``ROUTE_TABLE.json`` /
-        ``$REPRO_ROUTE_TABLE``) and finally to the backend's built-in
-        table (TPU keeps every op off the traversal kernels). An
-        :class:`~repro.core.index.ExecutionPolicy` table overrides this
-        per call/index.
+    force: route every eligible query to one path ("bruteforce" |
+        "loop"); a predicate kind brute force cannot express falls back
+        to the rule.
     max_executables: LRU bound on the exec_* executable cache — a long-
         lived service whose leaf count changes across rebuilds must not
         pin one compiled executable per historical N forever.
-    use_pallas: master switch for the fused kernel paths.
-    force: route every eligible query to one path ("bruteforce" |
-        "pallas" | "loop"); queries the forced path cannot express fall
-        back to the normal heuristic choice. ``REPRO_ENGINE_FORCE`` sets
-        this for the whole process (debugging; it beats every table).
-
-    brute_force_max_work / pallas_min_queries / pallas_min_leaves /
-    pallas_max_nodes / pallas_max_capacity: DEPRECATED — pass
-    ``route_table=RouteTable.single(bf_max_work=..., ...)`` instead.
     """
-    route_table: object = None
-    use_pallas: bool = True
     force: str | None = None
     max_executables: int = 256
-    # DEPRECATED crossover fields (warn-once shims; see _LEGACY_CROSSOVERS)
-    brute_force_max_work: int | None = None
-    pallas_min_queries: int | None = None
-    pallas_min_leaves: int | None = None
-    pallas_max_nodes: int | None = None
-    pallas_max_capacity: int | None = None
 
     def __post_init__(self):
-        routes = (ROUTE_BRUTEFORCE, ROUTE_PALLAS, ROUTE_LOOP)
+        routes = (ROUTE_BRUTEFORCE, ROUTE_LOOP)
         if self.force is not None and self.force not in routes:
             raise ValueError(f"force={self.force!r} is not one of {routes}")
-        env = os.environ.get("REPRO_ENGINE_FORCE")
-        if self.force is None and env:
-            if env not in routes:
-                raise ValueError(
-                    f"REPRO_ENGINE_FORCE={env!r} is not one of {routes}")
-            self.force = env
-        if isinstance(self.route_table, (str, os.PathLike)):
-            self.route_table = RT.RouteTable.load(os.fspath(self.route_table))
-        legacy = {name: getattr(self, name) for name in _LEGACY_CROSSOVERS
-                  if getattr(self, name) is not None}
-        if legacy:
-            from .index import _warn_deprecated
-            fields = ", ".join(sorted(legacy))
-            _warn_deprecated(
-                "EngineConfig.crossovers",
-                f"EngineConfig crossover fields ({fields}) are deprecated; "
-                "pass route_table=RouteTable.single(...) or autotune one "
-                "with `python -m benchmarks.autotune`")
-            base = (self.route_table.rule("default")
-                    if isinstance(self.route_table, RT.RouteTable)
-                    else RT.RouteRule())
-            rule = base.replace(**{_LEGACY_CROSSOVERS[k]: int(v)
-                                   for k, v in legacy.items()})
-            self.route_table = RT.RouteTable(
-                rules={"default": rule}, source="synthesized")
-
-
-def _pallas_spatial_call(tree, q_lo, q_hi, r, *, capacity, fine_sqrt,
-                         bq=256):
-    """The ONE spelling of the fused spatial kernel call, shared by the
-    direct route (pallas_fill) and the cached service executables."""
-    return bvh_traverse_spatial(
-        tree.node_lo, tree.node_hi, tree.rope, tree.left_child,
-        tree.range_last, tree.leaf_perm, q_lo, q_hi, r,
-        capacity=capacity, fine_sqrt=fine_sqrt, bq=bq)
-
-
-def _pallas_knn_call(tree, qc, *, k, bq=256):
-    return bvh_traverse_knn(tree.node_lo, tree.node_hi, tree.rope,
-                            tree.left_child, tree.leaf_perm, qc, k=k, bq=bq)
-
-
-#: predicate kinds the fused callback kernel can evaluate in-kernel
-#: (``node_overlap_test`` has no spelling for Nearest — kNN has its own
-#: kernel); everything else stays on the loop path.
-_CALLBACK_KINDS = (P.Intersects, P.RayIntersect, P.RayOrderedIntersect,
-                   P.RayNearest)
-
-
-def _state_width(state0) -> int:
-    """Widest per-query state row (elements) across the pytree leaves —
-    the VMEM-output analogue of a fill capacity."""
-    width = 1
-    for leaf in jax.tree_util.tree_leaves(state0):
-        w = 1
-        for s in jnp.shape(leaf)[1:]:
-            w *= int(s)
-        width = max(width, w)
-    return width
-
-
-def _spatial_rep(predicates):
-    """Unified (q_lo, q_hi, r) form of an Intersects batch, or None when
-    the geometry kind has no exact box/radius spelling."""
-    if not isinstance(predicates, P.Intersects):
-        return None
-    g = predicates.geom
-    if isinstance(g, G.Points):
-        z = jnp.zeros((g.coords.shape[0],), jnp.float32)
-        return g.coords, g.coords, z
-    if isinstance(g, G.Boxes):
-        z = jnp.zeros((g.lo.shape[0],), jnp.float32)
-        return g.lo, g.hi, z
-    if isinstance(g, G.Spheres):
-        return g.center, g.center, g.radius.astype(jnp.float32)
-    return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,7 +160,7 @@ class ExecInfo:
 
 
 class QueryEngine:
-    """Dispatches batched BVH queries to bruteforce / pallas / loop."""
+    """Dispatches batched BVH queries to bruteforce / loop."""
 
     #: reprolint lock discipline (analysis/locks.py): the executable cache
     #: and its stats counters are only coherent under _cache_lock — the
@@ -291,129 +176,26 @@ class QueryEngine:
             collections.OrderedDict()
         self._cache_lock = threading.Lock()
 
-    # -- route-table resolution (DESIGN.md §8 lookup order) ---------------
-    def table(self, policy=None) -> RT.RouteTable:
-        """Resolve the effective RouteTable: explicit policy table >
-        engine-config table > ambient persisted table > the built-in
-        table for the backend (:func:`~repro.core.route_table.
-        builtin_route_table`). (``force`` is orthogonal — checked inside
-        ``_pick``.)"""
-        t = getattr(policy, "route_table", None)
-        if t is None:
-            t = self.config.route_table
-        if t is None:
-            t = RT.default_route_table()
-        return t if t is not None else RT.builtin_route_table()
+    # -- routing (DESIGN.md §3) ------------------------------------------
+    def route_spatial(self, bvh, predicates, capacity: int | None = None) -> str:
+        """Route an Intersects batch for count/fill (`capacity` does not
+        enter the rule). Ray predicates take the loop."""
+        return self._pick(bvh.size(), len(predicates),
+                          isinstance(predicates, P.Intersects))
 
-    def _rule(self, op: str, bvh, policy) -> RT.RouteRule:
-        if policy is None:
-            policy = getattr(bvh, "policy", None)
-        return self.table(policy).rule(op)
+    def route_knn(self, bvh, predicates) -> str:
+        return self._pick(bvh.size(), len(predicates),
+                          isinstance(predicates, P.Nearest))
 
-    # -- routing ----------------------------------------------------------
-    def route_spatial(self, bvh, predicates, capacity: int | None = None,
-                      *, policy=None) -> str:
-        """Route an Intersects batch for count/fill. Ray predicates and
-        exotic geometries always take the loop path; fill passes whose
-        per-query buffer would blow the VMEM output block stay off pallas."""
-        cfg = self.config
-        rule = self._rule("spatial", bvh, policy)
-        q = len(predicates)
-        bf_ok = isinstance(predicates, P.Intersects)
-        pl_ok = (cfg.use_pallas and bvh.tree is not None and q > 0
-                 and bvh.pallas_values_ok
-                 and _spatial_rep(predicates) is not None
-                 and 2 * bvh.size() - 1 <= rule.pallas_max_nodes
-                 and (capacity is None or capacity <= rule.pallas_max_capacity))
-        return self._pick(bvh.size(), q, bf_ok, pl_ok, rule)
-
-    def route_knn(self, bvh, predicates, *, policy=None) -> str:
-        cfg = self.config
-        rule = self._rule("knn", bvh, policy)
-        q = len(predicates)
-        bf_ok = isinstance(predicates, P.Nearest)
-        pl_ok = (cfg.use_pallas and bvh.tree is not None and bf_ok and q > 0
-                 and bvh.pallas_values_ok
-                 and predicates.k <= rule.pallas_max_capacity
-                 and 2 * bvh.size() - 1 <= rule.pallas_max_nodes)
-        return self._pick(bvh.size(), q, bf_ok, pl_ok, rule)
-
-    def route_callback(self, bvh, predicates, state0=None, *,
-                       policy=None) -> str:
-        """Route a callback-flavor query: fused kernel (callback executes
-        in the traversal epilogue, no CSR ever materialized) vs the
-        vmapped while loop. Bruteforce cannot run callbacks, so a
-        bruteforce force falls back to the heuristic."""
-        cfg = self.config
-        rule = self._rule("callback", bvh, policy)
-        n = bvh.size()
-        q = len(predicates)
-        pl_ok = (cfg.use_pallas and bvh.tree is not None and q > 0
-                 and isinstance(predicates, _CALLBACK_KINDS)
-                 and 2 * n - 1 <= rule.pallas_max_nodes
-                 and (state0 is None
-                      or _state_width(state0) <= rule.pallas_max_capacity))
-        if cfg.force == ROUTE_PALLAS:
-            return ROUTE_PALLAS if pl_ok else ROUTE_LOOP
-        if cfg.force == ROUTE_LOOP:
-            return ROUTE_LOOP
-        if (pl_ok and q >= rule.pallas_min_queries
-                and n >= rule.pallas_min_leaves):
-            return ROUTE_PALLAS
-        return ROUTE_LOOP
-
-    def _pick(self, n: int, q: int, bf_ok: bool, pl_ok: bool,
-              rule: RT.RouteRule | None = None) -> str:
-        cfg = self.config
-        rule = rule if rule is not None else self.table().rule("spatial")
-        if cfg.force == ROUTE_BRUTEFORCE and bf_ok:
+    def _pick(self, n: int, q: int, bf_ok: bool) -> str:
+        force = self.config.force
+        if force == ROUTE_BRUTEFORCE and bf_ok:
             return ROUTE_BRUTEFORCE
-        if cfg.force == ROUTE_PALLAS and pl_ok:
-            return ROUTE_PALLAS
-        if cfg.force == ROUTE_LOOP:
+        if force == ROUTE_LOOP:
             return ROUTE_LOOP
-        if bf_ok and n * q <= rule.bf_max_work:
+        if bf_ok and n * q <= BF_MAX_WORK:
             return ROUTE_BRUTEFORCE
-        if (pl_ok and q >= rule.pallas_min_queries
-                and n >= rule.pallas_min_leaves):
-            return ROUTE_PALLAS
         return ROUTE_LOOP
-
-    # -- pallas execution --------------------------------------------------
-    def pallas_count(self, bvh, predicates, *, policy=None):
-        """(Q,) int32 match counts via the fused kernel."""
-        counts, _ = self.pallas_fill(bvh, predicates, 1, policy=policy)
-        return counts
-
-    def pallas_fill(self, bvh, predicates, capacity: int, *, policy=None):
-        """(counts, idx_buf): the ``collect_hits`` contract — full counts
-        plus the first `capacity` matched indices in traversal order."""
-        q_lo, q_hi, r = _spatial_rep(predicates)
-        # Points values take the sqrt-form fine test (distance <= r), the
-        # bit-exact twin of predicates.leaf_match_test for them
-        return _pallas_spatial_call(bvh.tree, q_lo, q_hi, r,
-                                    capacity=capacity,
-                                    fine_sqrt=isinstance(bvh.values, G.Points),
-                                    bq=self._rule("spatial", bvh, policy).block_q)
-
-    def pallas_knn(self, bvh, predicates, *, policy=None):
-        """(dists, idxs) (Q, k) via the fused kernel. Query point is the
-        geometry centroid — exactly what ``predicates.leaf_distance``
-        measures fine distances from."""
-        return _pallas_knn_call(bvh.tree, G.centroid(predicates.geom),
-                                k=predicates.k,
-                                bq=self._rule("knn", bvh, policy).block_q)
-
-    def pallas_callback(self, bvh, predicates, callback, state0, *,
-                        policy=None):
-        """Per-query final states via the fused callback kernel —
-        bit-identical to ``traversal.traverse`` (the conformance tests pin
-        it), but the callback runs inside the kernel loop."""
-        t = bvh.tree
-        return bvh_traverse_callback(
-            t.node_lo, t.node_hi, t.rope, t.left_child, t.range_last,
-            t.leaf_perm, bvh.values, predicates, callback, state0,
-            bq=self._rule("callback", bvh, policy).block_q)
 
     # -- brute-force fill (index-ordered; sets match traversal order) -----
     def bruteforce_fill(self, brute, predicates, capacity: int):
@@ -487,30 +269,13 @@ class QueryEngine:
         first `capacity` matched original indices per query (-1 padded).
         """
         route = self.route_spatial(bvh, predicates, capacity)
-        bq = self._rule("spatial", bvh, None).block_q
         # every value a traced body closes over is named IN the key —
         # reprolint TRC004 pins this (a closed-over value missing from the
         # key would let two different executables share one cache slot)
         nq = len(predicates)
-        fine_sqrt = isinstance(bvh.values, G.Points)
         getter = bvh._getter
-        key = (route, "spatial", capacity, bq, nq, fine_sqrt,
+        key = (route, "spatial", capacity, nq,
                getter) + self._shape_key(bvh, predicates)
-
-        if route == ROUTE_PALLAS:
-            def make():
-                def body(tree, q_lo, q_hi, r):
-                    self._note_trace()
-                    return _pallas_spatial_call(tree, q_lo, q_hi, r,
-                                                capacity=capacity,
-                                                fine_sqrt=fine_sqrt, bq=bq)
-                return jax.jit(body)
-
-            fn, hit = self._cached(key, make)
-            q_lo, q_hi, r = _spatial_rep(predicates)
-            out, kus = self._launch(fn, (bvh.tree, q_lo, q_hi, r),
-                                    route=route, op="spatial", hit=hit)
-            return out, ExecInfo(route, hit, kus)
 
         if route == ROUTE_BRUTEFORCE:
             def make():
@@ -551,21 +316,8 @@ class QueryEngine:
         count and whether its pruning bound was seeded."""
         route = self.route_knn(bvh, predicates)
         k = predicates.k
-        bq = self._rule("knn", bvh, None).block_q
         getter = bvh._getter
-        key = (route, "knn", k, bq, getter) + self._shape_key(bvh, predicates)
-
-        if route == ROUTE_PALLAS:
-            def make():
-                def body(tree, qc):
-                    self._note_trace()
-                    return _pallas_knn_call(tree, qc, k=k, bq=bq)
-                return jax.jit(body)
-
-            fn, hit = self._cached(key, make)
-            out, kus = self._launch(fn, (bvh.tree, G.centroid(predicates.geom)),
-                                    route=route, op="knn", hit=hit)
-            return out, ExecInfo(route, hit, kus)
+        key = (route, "knn", k, getter) + self._shape_key(bvh, predicates)
 
         if route == ROUTE_BRUTEFORCE:
             def make():

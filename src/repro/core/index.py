@@ -94,8 +94,8 @@ class ExecutionPolicy:
     execution-space argument, made explicit) and overridable per call via
     ``query(..., policy=...)``.
 
-    engine:        QueryEngine doing route selection (bruteforce / pallas /
-                   loop); None -> the process default engine.
+    engine:        QueryEngine doing route selection (bruteforce / loop);
+                   None -> the process default engine.
     device:        jax.Device the index (tree + values) is placed on at
                    build; None -> default device. Queries run where the
                    arrays live (XLA's async dispatch replaces per-call
@@ -109,18 +109,9 @@ class ExecutionPolicy:
                    states (default None -> elementwise psum, correct for
                    zero-initialized arithmetic states). Ignored by
                    single-process backends.
-    route_table:   a :class:`~repro.core.route_table.RouteTable` (or a
-                   path to a persisted one — loaded and validated here)
-                   overriding the engine's crossover thresholds for this
-                   index / call. None -> engine-config table, then the
-                   ambient persisted ``ROUTE_TABLE.json``, then defaults.
-                   A table only ever changes WHICH path serves a query,
-                   never the result.
     build_engine:  LBVH construction path: "pallas" (fused build kernels)
                    | "ref" (reference jit pipeline) | "auto"/None (the
-                   persisted table's choice, default pallas — both are
-                   bit-identical). ``REPRO_ENGINE_FORCE`` still beats
-                   this, for A/B debugging.
+                   fused build — both are bit-identical).
     ship_values:   distributed-only: opt in to shipping MATCHED values to
                    the originating shard so ``QueryResult.values`` is
                    populated (attach-data scenarios). Off by default —
@@ -134,15 +125,10 @@ class ExecutionPolicy:
     capacity: int | None = None
     max_doublings: int = 6
     combine: Any = None
-    route_table: Any = None
     build_engine: str | None = None
     ship_values: bool = False
 
     def __post_init__(self):
-        if isinstance(self.route_table, str):
-            from .route_table import RouteTable
-            object.__setattr__(self, "route_table",
-                               RouteTable.load(self.route_table))
         if self.build_engine is not None and \
                 self.build_engine not in ("auto", "pallas", "ref"):
             raise ValueError(f"build_engine={self.build_engine!r} is not "

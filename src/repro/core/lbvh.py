@@ -29,7 +29,6 @@ Node numbering: internal 0..N-2 (root = 0), leaves N-1..2N-2
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 
 import jax
@@ -269,26 +268,12 @@ def _refit_iterative(leaf_lo, leaf_hi, left_child, right_child):
 def _resolve_build_engine(engine: str) -> str:
     """Resolve the build-engine selector to "pallas" (the fused delta-RMQ
     build of ``kernels.lbvh_build``, a jit program on every backend) or
-    "ref" (the original unfused searches).
-
-    Order (DESIGN.md §8): REPRO_ENGINE_FORCE > explicit engine arg >
-    persisted RouteTable ``build_engine`` > default ("pallas" — the fused
-    path is exact, so it is safe to prefer everywhere).
+    "ref" (the original unfused searches). "auto" is the fused build: it
+    is exact, so it is safe to prefer everywhere.
     """
     if engine not in BUILD_ENGINES:
         raise ValueError(f"engine={engine!r} is not one of {BUILD_ENGINES}")
-    env = os.environ.get("REPRO_ENGINE_FORCE")
-    if env == "pallas":        # debugging override beats everything
-        return "pallas"
-    if env == "loop":          # "loop" is the engine's name for unfused
-        return "ref"
-    if engine != "auto":
-        return engine
-    from .route_table import default_route_table
-    table = default_route_table()
-    if table is not None and table.build_engine != "auto":
-        return table.build_engine
-    return "pallas"
+    return "pallas" if engine == "auto" else engine
 
 
 def build(boxes: Boxes, *, bits: int = 64, refit: str = "rmq",
@@ -298,9 +283,9 @@ def build(boxes: Boxes, *, bits: int = 64, refit: str = "rmq",
     bits: 32 or 64 (Morton code width, §2.6 — 64 is the 2.0 default).
     refit: "rmq" (sparse table) or "iterative" (readiness fixpoint).
     engine: "pallas" (fused delta-RMQ build, ``kernels.lbvh_build``),
-        "ref" (the original Karras searches), or "auto" (resolve via the
-        route table; see :func:`_resolve_build_engine`). Both engines
-        produce bit-identical trees — topology AND bounds.
+        "ref" (the original Karras searches), or "auto" (the fused
+        build). Both engines produce bit-identical trees — topology AND
+        bounds.
     """
     return _build_impl(boxes, bits=bits, refit=refit,
                        engine=_resolve_build_engine(engine))

@@ -64,7 +64,7 @@ class Nearest:
     is a candidate for query q only when
     ``leaf_labels[value_index] != query_labels[q]`` — Borůvka's "nearest
     outside my component" query (§2.4 EMST). Backends that cannot honor
-    it (the fused kernel, DistributedTree) must not be routed such
+    it (DistributedTree) must not be routed such
     predicates; the loop/bruteforce paths implement it exactly.
     """
     geom: object

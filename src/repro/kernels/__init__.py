@@ -2,8 +2,7 @@
 morton encoding, brute-force kNN (MXU), ray-box casting, flash attention.
 Validated in interpret mode against the pure-jnp oracles in ref.py."""
 from . import ops, ref
-from .bvh_traverse import bvh_traverse_knn, bvh_traverse_spatial
 from .ops import bruteforce_knn, flash_attention, morton64, ray_box_nearest
 
 __all__ = ["ops", "ref", "morton64", "bruteforce_knn", "ray_box_nearest",
-           "flash_attention", "bvh_traverse_spatial", "bvh_traverse_knn"]
+           "flash_attention"]

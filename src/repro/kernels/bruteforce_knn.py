@@ -129,9 +129,8 @@ def bruteforce_knn_pallas(queries, points, k: int, *, n_actual: int | None = Non
 # reprolint sanitizer spec (analysis/pallas_trace.py)
 # ---------------------------------------------------------------------------
 
-#: largest k the streaming top-k scratch is declared for — matches the
-#: route table's kNN pallas_max_capacity (the same VMEM pressure bounds
-#: both: the running-best scratch is (bq, k) x2 resident all sweep long)
+#: largest k the streaming top-k scratch is declared for: the running-best
+#: scratch is (bq, k) x2, resident all sweep long, so k bounds its VMEM
 REPROLINT_MAX_K = 256
 
 

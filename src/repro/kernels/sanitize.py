@@ -8,11 +8,9 @@ a chip, so a kernel asked for its interpret default on a TPU backend
 with REPRO_SANITIZE set raises. The checks are the kernels' public
 contracts:
 
-  * spatial fill — counts in [0, n], collected indices in [-1, n);
   * kNN — distances non-NaN and ascending per row, indices in [-1, n);
   * karras ranges — 0 <= first <= i <= last <= n-2+1 and gamma inside
-    [first, last) (the split must fall strictly inside the range);
-  * callback — no NaN in any float state leaf.
+    [first, last) (the split must fall strictly inside the range).
 
 Calls made from inside another trace (the engine's cached executables)
 see tracer outputs and skip the concrete checks — the tier-1 sanitize
@@ -27,8 +25,8 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["enabled", "interpret_default", "is_concrete", "check_spatial",
-           "check_knn", "check_karras", "check_state_tree"]
+__all__ = ["enabled", "interpret_default", "is_concrete", "check_knn",
+           "check_karras"]
 
 
 def enabled() -> bool:
@@ -59,18 +57,6 @@ def _fail(kernel: str, what: str):
         f"REPRO_SANITIZE: {kernel} violated its output contract: {what}")
 
 
-def check_spatial(counts, idx_buf, *, n: int, kernel: str):
-    import jax.numpy as jnp
-    if not (enabled() and is_concrete(counts, idx_buf)):
-        return
-    if counts.size and (int(jnp.min(counts)) < 0
-                        or int(jnp.max(counts)) > n):
-        _fail(kernel, f"counts outside [0, {n}]")
-    if idx_buf.size and (int(jnp.min(idx_buf)) < -1
-                         or int(jnp.max(idx_buf)) >= n):
-        _fail(kernel, f"collected indices outside [-1, {n})")
-
-
 def check_knn(dists, idxs, *, n: int, kernel: str):
     import jax.numpy as jnp
     if not (enabled() and is_concrete(dists, idxs)):
@@ -93,15 +79,3 @@ def check_karras(first, last, gamma, *, n: int, kernel: str):
     if not bool(jnp.all(ok)):
         _fail(kernel, "karras (first, last, gamma) outside the node "
                       "containment invariants")
-
-
-def check_state_tree(state, *, kernel: str):
-    import jax
-    import jax.numpy as jnp
-    leaves = jax.tree_util.tree_leaves(state)
-    if not (enabled() and is_concrete(*leaves)):
-        return
-    for leaf in leaves:
-        if jnp.issubdtype(leaf.dtype, jnp.floating) \
-                and bool(jnp.any(jnp.isnan(leaf))):
-            _fail(kernel, "NaN in callback state leaf")

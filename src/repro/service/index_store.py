@@ -70,7 +70,7 @@ class IndexStore:
                  build_engine: str | None = None):
         self.engine = engine if engine is not None else E.QueryEngine()
         # "pallas" | "ref" | "auto"/None; flows into every (re)build via
-        # ExecutionPolicy.build_engine (REPRO_ENGINE_FORCE still wins)
+        # ExecutionPolicy.build_engine
         self.build_engine = build_engine
         self.rebuild_threshold = float(rebuild_threshold)
         self.keep_versions = int(keep_versions)

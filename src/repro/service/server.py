@@ -51,7 +51,7 @@ class ServiceConfig:
 @dataclasses.dataclass(frozen=True)
 class RequestStats:
     kind: str
-    route: str            # bruteforce | pallas | loop
+    route: str            # bruteforce | loop
     bucket: int           # power-of-two batch the request rode in
     index_name: str
     index_version: int

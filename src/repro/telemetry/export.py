@@ -132,8 +132,8 @@ def validate_metrics_lines(metrics: dict) -> list:
 
 
 def summarize_spans(spans) -> dict:
-    """{span name: {count, total_us, max_us}} — the compact per-module
-    telemetry section ``benchmarks/run.py`` stamps into BENCH_*.json."""
+    """{span name: {count, total_us, max_us}} — a compact per-span
+    summary of drained spans."""
     out: dict = {}
     for s in spans:
         rec = out.setdefault(s.name, {"count": 0, "total_us": 0.0,

@@ -15,7 +15,7 @@ from repro.core import geometry as G, predicates as P
 from repro.core.brute_force import BruteForce
 from repro.core.bvh import BVH
 from repro.core.distributed import DistributedTree
-from repro.core.index import Index
+from repro.core.index import ExecutionPolicy, Index
 from repro.core import index as IX
 
 
@@ -109,35 +109,20 @@ def test_legacy_warnings_fire_once_per_spelling():
     IX._SEEN_DEPRECATIONS.clear()
 
 
-def test_engine_config_crossover_fields_warn_deprecation():
-    """ISSUE 7: the hand-measured crossover constants moved into
-    RouteTable; the old EngineConfig fields are warn-once shims that
-    synthesize a single-row table with the same thresholds."""
+def test_engine_config_has_two_fields():
+    """The engine's only knobs are a forced route and the executable-cache
+    bound: the crossover is one constant (``engine.BF_MAX_WORK``), and
+    neither the config nor an ExecutionPolicy carries a route table."""
+    import dataclasses
+
     from repro.core.engine import EngineConfig
-    from repro.core.route_table import RouteTable
 
-    IX._SEEN_DEPRECATIONS.clear()
-    with pytest.warns(DeprecationWarning, match="RouteTable"):
-        cfg = EngineConfig(brute_force_max_work=123, pallas_min_queries=7)
-    rule = cfg.route_table.rule("default")
-    assert cfg.route_table.source == "synthesized"
-    assert rule.bf_max_work == 123 and rule.pallas_min_queries == 7
-    # unset legacy fields keep the base-table values
-    assert rule.pallas_min_leaves == RouteTable.default().rule(
-        "default").pallas_min_leaves
-
-    # warn-once: a second legacy config does not warn again
-    import warnings
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        EngineConfig(brute_force_max_work=456)
-    assert not [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    IX._SEEN_DEPRECATIONS.clear()
-
-    # the new spelling is warning-free
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        EngineConfig(route_table=RouteTable.single(bf_max_work=123))
+    assert [f.name for f in dataclasses.fields(EngineConfig)] == \
+        ["force", "max_executables"]
+    assert "route_table" not in {
+        f.name for f in dataclasses.fields(ExecutionPolicy)}
+    with pytest.raises(TypeError):
+        EngineConfig(brute_force_max_work=123)
 
 
 def test_new_api_is_warning_free():

@@ -1,6 +1,10 @@
 """BVH queries vs the BruteForce oracle (the paper's own exactness bar:
 both indexes must return identical result sets), through the unified
-``Index.query()``."""
+``Index.query()``.
+
+The BVH side of each oracle comparison is pinned to the loop route: at
+these sizes the engine's rule would send an unforced batch to brute
+force, which is the oracle's own code."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,9 +13,16 @@ from hypothesis import given, settings, strategies as st
 from repro.core import geometry as G, predicates as P, callbacks as CB
 from repro.core.brute_force import BruteForce
 from repro.core.bvh import BVH
+from repro.core.engine import EngineConfig, QueryEngine
 from repro.core.index import ExecutionPolicy
 
 rng = np.random.default_rng(7)
+_LOOP = QueryEngine(EngineConfig(force="loop"))
+
+
+def _loop_bvh(vals):
+    """A BVH whose batches all take the while-loop traversal."""
+    return BVH(vals, engine=_LOOP)
 
 
 def _points(n, dim=3, seed=0):
@@ -24,7 +35,7 @@ def test_sphere_counts_match_bruteforce(dim):
     vals = _points(300, dim, seed=dim)
     q = _points(40, dim, seed=100 + dim)
     preds = P.intersects(G.Spheres(q.coords, jnp.full((40,), 0.3)))
-    a = BVH(vals).count(preds)
+    a = _loop_bvh(vals).count(preds)
     b = BruteForce(vals).count(preds)
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
@@ -33,7 +44,7 @@ def test_box_query_sets_match():
     vals = _points(400)
     lo = jnp.asarray(rng.uniform(0, 0.8, (30, 3)).astype(np.float32))
     preds = P.intersects(G.Boxes(lo, lo + 0.2))
-    ra = BVH(vals).query(preds)
+    ra = _loop_bvh(vals).query(preds)
     rb = BruteForce(vals).query(preds)
     ia, oa = np.asarray(ra.indices), np.asarray(ra.offsets)
     ib, ob = np.asarray(rb.indices), np.asarray(rb.offsets)
@@ -48,7 +59,7 @@ def test_knn_matches_bruteforce(k):
     vals = _points(500)
     q = _points(64, seed=5)
     preds = P.nearest(q, k=k)
-    ra = BVH(vals).query(preds)
+    ra = _loop_bvh(vals).query(preds)
     rb = BruteForce(vals).query(preds)
     assert np.allclose(np.asarray(ra.distances), np.asarray(rb.distances),
                        atol=1e-5)
@@ -65,7 +76,7 @@ def test_knn_against_triangles_fine_distance():
                        jnp.asarray(a + r.uniform(-.1, .1, (200, 3)).astype(np.float32)))
     q = _points(32, seed=12)
     preds = P.nearest(q, k=3)
-    da = BVH(tris).query(preds).distances
+    da = _loop_bvh(tris).query(preds).distances
     db = BruteForce(tris).query(preds).distances
     assert np.allclose(np.asarray(da), np.asarray(db), atol=1e-5)
 
@@ -128,7 +139,7 @@ def test_property_bvh_equals_bruteforce(n, seed, radius, dim):
     q = G.Points(jnp.asarray(r.uniform(0, 1, (8, dim)).astype(np.float32)))
     preds = P.intersects(G.Spheres(q.coords,
                                    jnp.full((8,), np.float32(radius))))
-    a = BVH(vals).count(preds)
+    a = _loop_bvh(vals).count(preds)
     b = BruteForce(vals).count(preds)
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
@@ -140,7 +151,7 @@ def test_csr_zero_total_matches():
     vals = _points(50, seed=50)
     far = jnp.asarray(rng.uniform(10, 11, (6, 3)).astype(np.float32))
     preds = P.intersects(G.Spheres(far, jnp.full((6,), 0.01, jnp.float32)))
-    for force in ("loop", "bruteforce", "pallas"):
+    for force in ("loop", "bruteforce"):
         eng = QueryEngine(EngineConfig(force=force))
         res = BVH(vals, engine=eng).query(preds)
         assert res.indices.shape == (0,)
@@ -162,7 +173,7 @@ def test_csr_capacity_clamping():
     assert (full == 60).all()
     cap = 7
     pol = ExecutionPolicy(max_doublings=0)
-    for force in ("loop", "bruteforce", "pallas"):
+    for force in ("loop", "bruteforce"):
         eng = QueryEngine(EngineConfig(force=force))
         res = BVH(vals, engine=eng).query(preds, capacity=cap,
                                           policy=pol.override(engine=eng))
@@ -183,7 +194,7 @@ def test_csr_capacity_overflow_doubling_retry():
     from repro.core.engine import EngineConfig, QueryEngine
     vals = _points(60, seed=51)
     preds = P.intersects(G.Spheres(vals.coords[:5], jnp.full((5,), 10.0)))
-    for force in ("loop", "bruteforce", "pallas"):
+    for force in ("loop", "bruteforce"):
         eng = QueryEngine(EngineConfig(force=force))
         res = BVH(vals, engine=eng).query(preds, capacity=7)
         v, idx, off, dists, overflow = res          # NamedTuple unpacking

@@ -1,6 +1,6 @@
 """CPU rehearsal of ``chip_smoke.py``: its entry point refuses a non-TPU
 backend, and its phases — the same code the chip runs — pass at a tiny
-size here, with the built-in TPU route table steering the engine so the
+size here, with the engine's brute-force crossover scaled down so the
 routes match what the chip takes (loop, and brute force for the small
 batches)."""
 import os
@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from repro.core import engine as E
-from repro.core import route_table as RT
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -30,19 +29,17 @@ def test_refuses_repro_sanitize(monkeypatch, capsys):
     assert "REPRO_SANITIZE" in capsys.readouterr().err
 
 
-def _tpu_routes_engine(bf_max_work: int):
-    """The built-in TPU table (no traversal kernels) with the brute-force
-    crossover scaled down to the rehearsal's sizes."""
-    tpu = RT.RouteTable.tpu_default()
-    rules = {op: r.replace(bf_max_work=bf_max_work)
-             for op, r in tpu.rules.items()}
-    return E.QueryEngine(E.EngineConfig(route_table=RT.RouteTable(rules)))
+def _tpu_routes_engine(monkeypatch, bf_max_work: int):
+    """The chip's rule with the brute-force crossover scaled down to the
+    rehearsal's sizes."""
+    monkeypatch.setattr(E, "BF_MAX_WORK", bf_max_work)
+    return E.QueryEngine()
 
 
-def test_single_chip_phases_pass_at_tiny_size():
+def test_single_chip_phases_pass_at_tiny_size(monkeypatch):
     routes = chip_smoke.smoke_single(
         n_large=2048, n_small=512, q=64, small_q=8, seed=3,
-        engine=_tpu_routes_engine(512 * 8))
+        engine=_tpu_routes_engine(monkeypatch, 512 * 8))
     assert routes["large Q=64"] == {"knn": "loop", "within": "loop",
                                     "ray": "loop"}
     assert routes["small Q=8"] == {"knn": "bruteforce",

@@ -1,21 +1,21 @@
-"""QueryEngine dispatch + fused Pallas traversal kernel vs the BruteForce
-oracle (interpret mode on CPU — identical kernel-body semantics)."""
+"""QueryEngine dispatch, and both routes (brute force and the while loop)
+against numpy all-pairs oracles."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import engine as E
 from repro.core import geometry as G, predicates as P
-from repro.core.brute_force import BruteForce
 from repro.core.bvh import BVH
-from repro.core.engine import (ROUTE_BRUTEFORCE, ROUTE_LOOP, ROUTE_PALLAS,
-                               EngineConfig, QueryEngine)
-from repro.core.route_table import RouteTable
+from repro.core.engine import (ROUTE_BRUTEFORCE, ROUTE_LOOP, EngineConfig,
+                               QueryEngine)
 from repro.core.lbvh import build
 from repro.core.traversal import traverse
 from repro.core import callbacks as CB
-from repro.kernels.bvh_traverse import bvh_traverse_knn, bvh_traverse_spatial
 
 rng = np.random.default_rng(17)
+
+ROUTES = pytest.mark.parametrize("route", [ROUTE_LOOP, ROUTE_BRUTEFORCE])
 
 
 def _pts(n, dim=3, seed=0, lo=0.0, hi=1.0):
@@ -23,133 +23,152 @@ def _pts(n, dim=3, seed=0, lo=0.0, hi=1.0):
     return jnp.asarray(r.uniform(lo, hi, (n, dim)).astype(np.float32))
 
 
-def _tree_arrays(tree):
-    return (tree.node_lo, tree.node_hi, tree.rope, tree.left_child,
-            tree.range_last, tree.leaf_perm)
+def _forced(values, route):
+    eng = QueryEngine(EngineConfig(force=route))
+    return BVH(values, engine=eng), eng
+
+
+def _np_dist(a, b):
+    """(len(a), len(b)) float64 euclidean distances."""
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    return np.sqrt(((a[:, None, :] - b[None, :, :]) ** 2).sum(-1))
+
+
+def _np_box_dist(p, lo, hi):
+    """(len(p), len(lo)) float64 distance from each point to each box."""
+    p = np.asarray(p, np.float64)[:, None, :]
+    lo = np.asarray(lo, np.float64)[None]
+    hi = np.asarray(hi, np.float64)[None]
+    gap = np.maximum(np.maximum(lo - p, p - hi), 0.0)
+    return np.sqrt((gap ** 2).sum(-1))
+
+
+def _csr_sets(res):
+    idx, off = np.asarray(res.indices), np.asarray(res.offsets)
+    return [set(idx[off[i]:off[i + 1]].tolist()) for i in range(len(off) - 1)]
 
 
 # ---------------------------------------------------------------------------
-# kernel vs oracle: spatial
+# both routes vs the all-pairs oracle: spatial
 # ---------------------------------------------------------------------------
 
+@ROUTES
 @pytest.mark.parametrize("n,q,dim", [(64, 16, 2), (300, 40, 3), (513, 33, 5)])
-def test_pallas_spatial_sphere_counts_bit_exact(n, q, dim):
+def test_spatial_sphere_counts_bit_exact(n, q, dim, route):
     pts = _pts(n, dim, seed=n)
     qp = _pts(q, dim, seed=1000 + n)
     r = jnp.full((q,), 0.3, jnp.float32)
-    vals = G.Points(pts)
-    tree = build(G.Boxes(pts, pts))
-    cnt, _ = bvh_traverse_spatial(*_tree_arrays(tree), qp, qp, r,
-                                  capacity=1, fine_sqrt=True, interpret=True)
-    want = BruteForce(vals).count(P.intersects(G.Spheres(qp, r)))
-    assert np.array_equal(np.asarray(cnt), np.asarray(want))
+    preds = P.intersects(G.Spheres(qp, r))
+    bvh, eng = _forced(G.Points(pts), route)
+    assert eng.route_spatial(bvh, preds) == route
+    want = (_np_dist(qp, pts) <= 0.3).sum(-1)
+    assert np.array_equal(np.asarray(bvh.count(preds)), want)
 
 
+@ROUTES
 @pytest.mark.parametrize("kind", ["point", "box", "sphere"])
-def test_pallas_spatial_all_query_kinds_vs_oracle(kind):
-    """Counts AND match sets identical to BruteForce for every query kind
-    the unified (q_lo, q_hi, r²) representation covers — over Boxes values."""
+def test_spatial_all_query_kinds_vs_oracle(kind, route):
+    """Counts AND match sets identical to the oracle for point, box and
+    sphere queries over Boxes values."""
     r0 = np.random.default_rng(3)
     lo = jnp.asarray(r0.uniform(0, 1, (200, 3)).astype(np.float32))
-    boxes = G.Boxes(lo, lo + jnp.asarray(
-        r0.uniform(0.01, 0.2, (200, 3)).astype(np.float32)))
+    hi = lo + jnp.asarray(r0.uniform(0.01, 0.2, (200, 3)).astype(np.float32))
     q = 48
     qp = _pts(q, 3, seed=4)
+    lo_n, hi_n, qp_n = (np.asarray(a) for a in (lo, hi, qp))
     if kind == "point":
         preds = P.intersects(G.Points(qp))
-        q_lo, q_hi, rad = qp, qp, jnp.zeros((q,), jnp.float32)
+        want = ((lo_n[None] <= qp_n[:, None]) & (qp_n[:, None] <= hi_n[None])
+                ).all(-1)
     elif kind == "box":
         preds = P.intersects(G.Boxes(qp, qp + 0.25))
-        q_lo, q_hi, rad = qp, qp + 0.25, jnp.zeros((q,), jnp.float32)
+        q_hi = np.asarray(qp + 0.25)
+        want = ((qp_n[:, None] <= hi_n[None]) & (lo_n[None] <= q_hi[:, None])
+                ).all(-1)
     else:
-        rad = jnp.full((q,), 0.2, jnp.float32)
-        preds = P.intersects(G.Spheres(qp, rad))
-        q_lo, q_hi = qp, qp
-    tree = build(boxes)
-    bf = BruteForce(boxes)
-    want = np.asarray(bf.count(preds))
-    cap = max(int(want.max()), 1)
-    cnt, buf = bvh_traverse_spatial(*_tree_arrays(tree), q_lo, q_hi, rad,
-                                    capacity=cap, interpret=True)
-    assert np.array_equal(np.asarray(cnt), want)
-    rb = bf.query(preds)
-    ib, ob = rb.indices, rb.offsets
-    ib, ob = np.asarray(ib), np.asarray(ob)
-    buf = np.asarray(buf)
-    for i in range(q):
-        assert set(buf[i, :want[i]].tolist()) == set(ib[ob[i]:ob[i + 1]].tolist())
+        preds = P.intersects(G.Spheres(qp, jnp.full((q,), 0.2, jnp.float32)))
+        want = _np_box_dist(qp_n, lo_n, hi_n) <= 0.2
+    bvh, eng = _forced(G.Boxes(lo, hi), route)
+    assert eng.route_spatial(bvh, preds) == route
+    assert np.array_equal(np.asarray(bvh.count(preds)), want.sum(-1))
+    assert _csr_sets(bvh.query(preds)) == \
+        [set(np.flatnonzero(row).tolist()) for row in want]
 
 
-def test_pallas_spatial_capacity_clamps_but_counts_full():
+@ROUTES
+def test_spatial_capacity_clamps_but_counts_full(route):
     pts = _pts(400, 3, seed=9)
     qp = _pts(32, 3, seed=10)
     r = jnp.full((32,), 0.4, jnp.float32)
-    tree = build(G.Boxes(pts, pts))
-    cnt_full, _ = bvh_traverse_spatial(*_tree_arrays(tree), qp, qp, r,
-                                       capacity=1, fine_sqrt=True,
-                                       interpret=True)
-    cnt, buf = bvh_traverse_spatial(*_tree_arrays(tree), qp, qp, r,
-                                    capacity=5, fine_sqrt=True,
-                                    interpret=True)
-    assert np.array_equal(np.asarray(cnt), np.asarray(cnt_full))
+    preds = P.intersects(G.Spheres(qp, r))
+    bvh, eng = _forced(G.Points(pts), route)
+    match = _np_dist(qp, pts) <= 0.4
+    (cnt, buf), info = eng.exec_spatial(bvh, preds, 5)
+    assert info.route == route
+    assert np.array_equal(np.asarray(cnt), match.sum(-1))
     buf = np.asarray(buf)
     stored = (buf >= 0).sum(1)
-    assert np.array_equal(stored, np.minimum(np.asarray(cnt), 5))
+    assert np.array_equal(stored, np.minimum(match.sum(-1), 5))
+    for i in range(32):
+        assert set(buf[i, :stored[i]].tolist()) <= \
+            set(np.flatnonzero(match[i]).tolist())
 
 
-def test_pallas_spatial_min_pos_matches_loop_pair_traversal():
-    """The range_last position filter (§2.6 pair traversal) must prune the
-    same subtrees as the while-loop implementation."""
+def test_pair_traversal_min_pos_vs_oracle():
+    """The range_last position filter (§2.6 pair traversal) of the loop:
+    with each query's own sorted position as ``min_pos``, a self-join
+    counts exactly the matches at later sorted positions. Brute force has
+    no sorted positions, so this case runs on the loop alone."""
     pts = _pts(128, 3, seed=11)
     tree = build(G.Boxes(pts, pts))
-    vals = G.Points(pts)
     # self-join: every point queries a sphere around itself
     r = jnp.full((128,), 0.25, jnp.float32)
     preds = P.intersects(G.Spheres(pts, r))
     # min_pos = own sorted position -> strict upper-triangle join
-    inv_perm = jnp.zeros((128,), jnp.int32).at[tree.leaf_perm].set(
-        jnp.arange(128, dtype=jnp.int32))
+    inv_perm = np.zeros((128,), np.int32)
+    inv_perm[np.asarray(tree.leaf_perm)] = np.arange(128, dtype=np.int32)
     cb, s0 = CB.counting()
     s0 = jnp.broadcast_to(s0, (128,))
-    want = traverse(tree, vals, preds, cb, s0, min_pos=inv_perm)
-    cnt, _ = bvh_traverse_spatial(*_tree_arrays(tree), pts, pts, r,
-                                  capacity=1, fine_sqrt=True,
-                                  min_pos=inv_perm, interpret=True)
-    assert np.array_equal(np.asarray(cnt), np.asarray(want))
+    cnt = np.asarray(traverse(tree, G.Points(pts), preds, cb, s0,
+                              min_pos=jnp.asarray(inv_perm)))
+    match = _np_dist(pts, pts) <= 0.25
+    later = inv_perm[None, :] > inv_perm[:, None]
+    assert np.array_equal(cnt, (match & later).sum(-1))
     # upper-triangle invariant: sum == (total pairs - Q self matches) / 2
-    full = BruteForce(vals).count(preds)
-    assert int(np.asarray(cnt).sum()) == (int(np.asarray(full).sum()) - 128) // 2
+    assert int(cnt.sum()) == (int(match.sum()) - 128) // 2
 
 
 # ---------------------------------------------------------------------------
-# kernel vs oracle: kNN
+# both routes vs the all-pairs oracle: kNN
 # ---------------------------------------------------------------------------
 
+@ROUTES
 @pytest.mark.parametrize("n,q,dim,k", [(64, 16, 2, 1), (500, 64, 3, 8),
                                        (513, 33, 5, 4), (100, 8, 3, 17)])
-def test_pallas_knn_vs_oracle(n, q, dim, k):
+def test_knn_vs_oracle(n, q, dim, k, route):
     pts = _pts(n, dim, seed=n + 1)
     qp = _pts(q, dim, seed=2000 + n)
-    tree = build(G.Boxes(pts, pts))
-    d1, i1 = bvh_traverse_knn(tree.node_lo, tree.node_hi, tree.rope,
-                              tree.left_child, tree.leaf_perm, qp, k=k,
-                              interpret=True)
-    r2 = BruteForce(G.Points(pts)).query(P.nearest(G.Points(qp), k=k))
-    d2, i2 = r2.distances, r2.indices
-    assert np.allclose(np.asarray(d1), np.asarray(d2), atol=1e-4)
-    # indices may differ only across exact-distance ties
-    same = np.asarray(i1) == np.asarray(i2)
-    if not same.all():
-        assert np.allclose(np.asarray(d1)[~same], np.asarray(d2)[~same],
-                           atol=1e-5)
+    preds = P.nearest(G.Points(qp), k=k)
+    bvh, eng = _forced(G.Points(pts), route)
+    assert eng.route_knn(bvh, preds) == route
+    res = bvh.query(preds)
+    d1, i1 = np.asarray(res.distances), np.asarray(res.indices)
+    d1, i1 = d1.reshape(q, k), i1.reshape(q, k)
+    dist = _np_dist(qp, pts)
+    assert np.allclose(d1, np.sort(dist, axis=-1)[:, :k], atol=1e-4)
+    # each returned index is at its returned distance: indices may differ
+    # from the oracle's only across exact-distance ties
+    assert np.allclose(np.take_along_axis(dist, i1, 1), d1, atol=1e-4)
 
 
-def test_pallas_knn_k_exceeds_n_pads_with_inf():
+@ROUTES
+def test_knn_k_exceeds_n_pads_with_inf(route):
     pts = _pts(8, 3, seed=5)
-    tree = build(G.Boxes(pts, pts))
-    d, i = bvh_traverse_knn(tree.node_lo, tree.node_hi, tree.rope,
-                            tree.left_child, tree.leaf_perm,
-                            _pts(4, 3, seed=6), k=12, interpret=True)
+    preds = P.nearest(G.Points(_pts(4, 3, seed=6)), k=12)
+    bvh, eng = _forced(G.Points(pts), route)
+    assert eng.route_knn(bvh, preds) == route
+    d, i = bvh._knn_impl(preds, bvh.policy)
     d, i = np.asarray(d), np.asarray(i)
     assert (i[:, :8] >= 0).all() and (i[:, 8:] == -1).all()
     assert np.isinf(d[:, 8:]).all()
@@ -164,58 +183,77 @@ def _mk(n=600, engine=None):
     return BVH(G.Points(_pts(n, 3, seed=42)), engine=engine)
 
 
+def test_bf_max_work_is_the_chip_crossover():
+    """The crossover the chip ran at (a batch of 1024 queries takes brute
+    force up to 4096 points)."""
+    assert E.BF_MAX_WORK == 1 << 22
+
+
 def test_route_small_work_goes_bruteforce():
-    eng = QueryEngine(EngineConfig(
-        route_table=RouteTable.single(bf_max_work=1 << 22)))
+    eng = QueryEngine()
     bvh = _mk(600, eng)
     preds = P.intersects(G.Spheres(_pts(10, 3, seed=1), jnp.full((10,), 0.1)))
     assert eng.route_spatial(bvh, preds) == ROUTE_BRUTEFORCE
+    knn = P.nearest(G.Points(_pts(10, 3, seed=2)), k=4)
+    assert eng.route_knn(bvh, knn) == ROUTE_BRUTEFORCE
 
 
-def test_route_large_batch_goes_pallas():
-    eng = QueryEngine(EngineConfig(route_table=RouteTable.single(
-        bf_max_work=100, pallas_min_queries=8, pallas_min_leaves=8)))
-    bvh = _mk(600, eng)
-    preds = P.intersects(G.Spheres(_pts(64, 3, seed=1), jnp.full((64,), 0.1)))
-    assert eng.route_spatial(bvh, preds) == ROUTE_PALLAS
-    knn = P.nearest(G.Points(_pts(64, 3, seed=2)), k=4)
-    assert eng.route_knn(bvh, knn) == ROUTE_PALLAS
+def test_route_large_work_goes_loop():
+    """N·Q at BF_MAX_WORK still takes brute force; one query more takes
+    the loop, for both ops."""
+    eng = QueryEngine()
+    bvh = _mk(1024, eng)
+    at = E.BF_MAX_WORK // 1024
+    for q, want in ((at, ROUTE_BRUTEFORCE), (at + 1, ROUTE_LOOP)):
+        qp = _pts(q, 3, seed=q)
+        spatial = P.intersects(G.Spheres(qp, jnp.full((q,), 0.1)))
+        assert eng.route_spatial(bvh, spatial) == want
+        assert eng.route_knn(bvh, P.nearest(G.Points(qp), k=4)) == want
 
 
 def test_route_ineligible_values_fall_back_to_loop():
-    """Triangles' fine test is not a box test -> never the fused kernel."""
+    """Value geometry does not enter the rule: a Triangles index routes
+    Intersects batches by N·Q like a Points one, and what brute force
+    cannot answer over it (ray predicates) takes the loop, forced or not."""
     r = np.random.default_rng(2)
     a = jnp.asarray(r.uniform(0, 1, (64, 3)).astype(np.float32))
     tris = G.Triangles(a, a + 0.05, a + 0.1)
-    eng = QueryEngine(EngineConfig(route_table=RouteTable.single(
-        bf_max_work=0, pallas_min_queries=1, pallas_min_leaves=1)))
-    bvh = BVH(tris, engine=eng)
     preds = P.intersects(G.Spheres(_pts(32, 3, seed=3), jnp.full((32,), 0.2)))
-    assert eng.route_spatial(bvh, preds) == ROUTE_LOOP
+    rays = P.RayIntersect(G.Rays(_pts(8, 3, seed=4), _pts(8, 3, seed=5)))
+    for force, spatial in ((None, ROUTE_BRUTEFORCE),
+                           (ROUTE_BRUTEFORCE, ROUTE_BRUTEFORCE),
+                           (ROUTE_LOOP, ROUTE_LOOP)):
+        eng = QueryEngine(EngineConfig(force=force))
+        bvh = BVH(tris, engine=eng)
+        assert eng.route_spatial(bvh, preds) == spatial
+        assert eng.route_spatial(bvh, rays) == ROUTE_LOOP
 
 
 def test_route_ray_predicates_always_loop():
-    eng = QueryEngine(EngineConfig(
-        route_table=RouteTable.single(bf_max_work=1 << 30)))
-    bvh = _mk(600, eng)
-    rays = P.RayNearest(G.Rays(_pts(8, 3, seed=4), _pts(8, 3, seed=5)), 1)
-    assert eng.route_spatial(bvh, rays) == ROUTE_LOOP
+    for force in (None, ROUTE_BRUTEFORCE):
+        eng = QueryEngine(EngineConfig(force=force))
+        bvh = _mk(600, eng)
+        rays = P.RayNearest(G.Rays(_pts(8, 3, seed=4), _pts(8, 3, seed=5)), 1)
+        assert eng.route_spatial(bvh, rays) == ROUTE_LOOP
+        assert eng.route_knn(bvh, rays) == ROUTE_LOOP
 
 
 def test_route_force_override():
-    for force in (ROUTE_BRUTEFORCE, ROUTE_PALLAS, ROUTE_LOOP):
+    for force in (ROUTE_BRUTEFORCE, ROUTE_LOOP):
         eng = QueryEngine(EngineConfig(force=force))
         bvh = _mk(600, eng)
         preds = P.intersects(G.Spheres(_pts(16, 3, seed=6),
                                        jnp.full((16,), 0.1)))
         assert eng.route_spatial(bvh, preds) == force
+    with pytest.raises(ValueError, match="force='pallas'"):
+        EngineConfig(force="pallas")
 
 
 # ---------------------------------------------------------------------------
 # end-to-end: BVH results are identical on every route
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("force", [ROUTE_LOOP, ROUTE_BRUTEFORCE, ROUTE_PALLAS])
+@pytest.mark.parametrize("force", [ROUTE_LOOP, ROUTE_BRUTEFORCE])
 def test_bvh_query_results_path_independent(force):
     vals = G.Points(_pts(300, 3, seed=7))
     preds = P.intersects(G.Spheres(_pts(24, 3, seed=8),

@@ -15,6 +15,7 @@ from repro.core import geometry as G, predicates as P, callbacks as CB
 from repro.core.brute_force import BruteForce
 from repro.core.bvh import BVH
 from repro.core.distributed import DistributedTree
+from repro.core.engine import EngineConfig, QueryEngine
 from repro.core.index import Index, QueryResult
 
 N, Q, DIM = 200, 16, 3
@@ -34,7 +35,9 @@ _D = np.linalg.norm(_QP[:, None] - _PTS[None], axis=-1)
 def make_index(kind, coords=None) -> Index:
     values = G.Points(jnp.asarray(_PTS if coords is None else coords))
     if kind == "bvh":
-        return BVH(values)
+        # Pinned to the loop route: at N * Q <= engine.BF_MAX_WORK an
+        # unforced BVH would answer through BruteForce's own code.
+        return BVH(values, engine=QueryEngine(EngineConfig(force="loop")))
     if kind == "bruteforce":
         return BruteForce(values)
     mesh = make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))

@@ -11,7 +11,7 @@ import pytest
 
 from repro.core import geometry as G, predicates as P
 from repro.core.bvh import BVH
-from repro.core.engine import (ROUTE_BRUTEFORCE, ROUTE_LOOP, ROUTE_PALLAS,
+from repro.core.engine import (ROUTE_BRUTEFORCE, ROUTE_LOOP,
                                EngineConfig, QueryEngine)
 from repro.service import (QueryServer, ServiceConfig, knn_request,
                            ray_request, within_request)
@@ -158,11 +158,11 @@ def test_server_scatter_matches_direct_queries():
     # stats populated
     for r, kind in zip(rs, ("knn", "within", "ray")):
         assert r.stats.kind == kind
-        assert r.stats.route in (ROUTE_BRUTEFORCE, ROUTE_PALLAS, ROUTE_LOOP)
+        assert r.stats.route in (ROUTE_BRUTEFORCE, ROUTE_LOOP)
         assert r.stats.bucket == bucket_size(len(r.dists if r.counts is None
                                                 else r.counts))
         assert (r.stats.index_name, r.stats.index_version) == ("default", 1)
-    assert rs[2].stats.route == ROUTE_LOOP      # rays never hit the kernel
+    assert rs[2].stats.route == ROUTE_LOOP      # rays always take the loop
 
 
 def test_server_within_overflow_flagged_per_request():
@@ -234,27 +234,22 @@ def test_exec_cache_keys_split_by_route_and_shape():
 
 
 def test_exec_paths_agree_across_forced_routes():
-    """The same bucket served by all three routes returns identical counts
-    and match sets (DESIGN.md §3 invariant, now through the service)."""
+    """The same bucket served by both routes returns identical counts and
+    match sets (DESIGN.md §3 invariant, now through the service)."""
     pts = _pts(400, seed=17)
     q = _pts(24, 18)
     results = {}
-    for force in (ROUTE_LOOP, ROUTE_BRUTEFORCE, ROUTE_PALLAS):
-        from repro.core.route_table import RouteTable
-        eng = QueryEngine(EngineConfig(force=force, route_table=RouteTable.
-                                       single(pallas_min_queries=1,
-                                              pallas_min_leaves=1)))
+    for force in (ROUTE_LOOP, ROUTE_BRUTEFORCE):
+        eng = QueryEngine(EngineConfig(force=force))
         srv = QueryServer(engine=eng, config=ServiceConfig(capacity=32))
         srv.create_index("default", G.Points(jnp.asarray(pts)))
         r = srv.handle([within_request(q, 0.2)])[0]
         assert r.stats.route == force
         results[force] = r
-    ref = results[ROUTE_LOOP]
-    for force in (ROUTE_BRUTEFORCE, ROUTE_PALLAS):
-        got = results[force]
-        assert np.array_equal(got.counts, ref.counts)
-        for ra, rb, c in zip(got.idxs, ref.idxs, ref.counts):
-            assert set(ra[:c].tolist()) == set(rb[:c].tolist())
+    ref, got = results[ROUTE_LOOP], results[ROUTE_BRUTEFORCE]
+    assert np.array_equal(got.counts, ref.counts)
+    for ra, rb, c in zip(got.idxs, ref.idxs, ref.counts):
+        assert set(ra[:c].tolist()) == set(rb[:c].tolist())
 
 
 def test_server_survives_degenerate_index():

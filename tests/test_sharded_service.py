@@ -15,6 +15,7 @@ import pytest
 from repro.compat import make_mesh
 from repro.core import geometry as G
 from repro.core.distributed import DistributedTree
+from repro.core.engine import EngineConfig, QueryEngine
 from repro.service import (IndexStore, PipelineConfig, QueryServer,
                            ServiceConfig, ServingPipeline, ShardedIndexStore,
                            knn_request, ray_request, within_request)
@@ -93,7 +94,10 @@ def test_sharded_serving_matches_single_device():
     sharded = QueryServer(store=ShardedIndexStore(_mesh1(), "data"),
                           config=cfg)
     sharded.create_index("default", pts)
-    plain = QueryServer(store=IndexStore(), config=cfg)
+    # the shards walk the loop: so does the reference, so that an
+    # over-capacity row keeps the same first hits
+    plain = QueryServer(store=IndexStore(QueryEngine(EngineConfig(
+        force="loop"))), config=cfg)
     plain.create_index("default", G.Points(jnp.asarray(pts)))
 
     qa = _pts(5, seed=6)

@@ -4,9 +4,9 @@ largest sizes the route allows.
 
 Every test here runs in this one file's worker: the topology is described
 inside the module-scoped ``topo`` fixture, never at import, so only the
-worker given this file loads the TPU compiler. Kernels kept OFF the TPU
-route (a built-in rule, not a run-time fallback) carry a strict xfail
-with the compiler's message: the PR that makes one compile flips it.
+worker given this file loads the TPU compiler. The Karras kernel, kept
+OFF the TPU build (the jit twin runs there), carries a strict xfail with
+the compiler's message: the PR that makes it compile flips it.
 """
 import re
 
@@ -20,10 +20,8 @@ from repro.core import geometry as G
 from repro.core import lbvh
 from repro.core import predicates as P
 from repro.core import traversal as T
+from repro.core import engine as E
 from repro.core.brute_force import BruteForce
-from repro.core.route_table import TPU_TRAVERSAL_REASON, RouteTable
-from repro.kernels import bvh_callback as BC
-from repro.kernels import bvh_traverse as BT
 from repro.kernels import lbvh_build as KB
 from repro.kernels.bruteforce_knn import bruteforce_knn_pallas
 
@@ -156,9 +154,9 @@ def test_loop_traversal_reads_the_node_table_in_place(spec, body):
 
 @pytest.mark.parametrize("op", ["knn", "fill"])
 def test_bruteforce_route_compiles(spec, op):
-    """The brute-force route at its crossover: N*Q = bf_max_work."""
+    """The brute-force route at its crossover: N*Q = BF_MAX_WORK."""
     n = 1 << 16
-    q = RouteTable.tpu_default().rule("knn").bf_max_work // n
+    q = E.BF_MAX_WORK // n
 
     def fn(v, a):
         bf = BruteForce(G.Points(v))
@@ -182,7 +180,7 @@ def test_bruteforce_knn_kernel_compiles(spec):
 
 
 # ---------------------------------------------------------------------------
-# off the TPU route, each by an explicit rule — strict xfails
+# off the TPU route by an explicit rule — a strict xfail
 # ---------------------------------------------------------------------------
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
@@ -192,51 +190,3 @@ def test_karras_kernel_compiles_at_its_leaf_limit(spec):
     fn = jax.jit(lambda h, lo, ix: KB.karras_ranges_pallas(
         h, lo, ix, n, 20, interpret=False))
     fn.lower(spec((n,), u32), spec((n,), u32), spec((n,), u32)).compile()
-
-
-def _route_limit(op):
-    rule = RouteTable.default().rule(op)
-    return (rule.pallas_max_nodes + 1) // 2, rule
-
-
-def _kernel_tree(spec, n):
-    t = _tree(spec, n)
-    return t.node_lo, t.node_hi, t.rope, t.left_child, t.range_last, \
-        t.leaf_perm
-
-
-TRAVERSAL_XFAIL = pytest.mark.xfail(strict=True, raises=ValueError,
-                                    reason=TPU_TRAVERSAL_REASON)
-
-
-@TRAVERSAL_XFAIL
-def test_spatial_kernel_compiles_at_route_limit(spec):
-    n, rule = _route_limit("spatial")
-    fn = jax.jit(lambda *a: BT._bvh_traverse_spatial_jit.__wrapped__(
-        *a, capacity=64, fine_sqrt=True, bq=rule.block_q, interpret=False))
-    fn.lower(*_kernel_tree(spec, n), spec((Q_BUCKET, 3), f32),
-             spec((Q_BUCKET, 3), f32), spec((Q_BUCKET,), f32)).compile()
-
-
-@TRAVERSAL_XFAIL
-def test_knn_kernel_compiles_at_route_limit(spec):
-    n, rule = _route_limit("knn")
-    lo, hi, rope, left, _, perm = _kernel_tree(spec, n)
-    fn = jax.jit(lambda *a: BT._bvh_traverse_knn_jit.__wrapped__(
-        *a, k=8, bq=rule.block_q, interpret=False))
-    fn.lower(lo, hi, rope, left, perm, spec((Q_BUCKET, 3), f32)).compile()
-
-
-@TRAVERSAL_XFAIL
-def test_callback_kernel_compiles_at_route_limit(spec):
-    n, rule = _route_limit("callback")
-    cb, _ = CB.counting()
-
-    def fn(lo, hi, rope, left, rlast, perm, v, a):
-        preds = P.intersects(G.Spheres(a, jnp.full((Q_BUCKET,), 0.01, f32)))
-        return BC._bvh_traverse_callback_jit.__wrapped__(
-            lo, hi, rope, left, rlast, perm, G.Points(v), preds, cb,
-            jnp.zeros((Q_BUCKET,), i32), bq=rule.block_q, interpret=False)
-
-    jax.jit(fn).lower(*_kernel_tree(spec, n), spec((n, 3), f32),
-                      spec((Q_BUCKET, 3), f32)).compile()
